@@ -143,6 +143,9 @@ def run_experiment(name, kind, args):
     env = dict(os.environ)
     env["PYTHONPATH"] = _SRC
     env.pop("XLA_FLAGS", None)
+    # the dry-run compiles against 512 placeholder host devices and must
+    # never claim an accelerator; the parent stays off JAX altogether
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _CHILD, json.dumps(spec)],
                        capture_output=True, text=True, env=env, timeout=3000)
     for line in r.stdout.splitlines():

@@ -22,11 +22,12 @@ import numpy as np
 
 def run(args):
     import jax
+    from repro.launch.mesh import make_mesh
     from repro.core import acoustic, dsl as st
 
     shape = tuple(args.shape)
     if args.distributed:
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         backend = st.distributed(grid_axes=("data", "model", None),
                                  overlap=True)
     else:
@@ -85,4 +86,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

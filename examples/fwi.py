@@ -63,6 +63,7 @@ def main():
     lr = args.lr or 0.03
 
     import jax
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
 
     from repro.core import acoustic, dsl as st
@@ -104,7 +105,7 @@ def main():
     if args.mesh:
         if n % args.mesh:
             raise SystemExit(f"--mesh {args.mesh} must divide n={n}")
-        mesh = jax.make_mesh((args.mesh,), ("data",))
+        mesh = make_mesh((args.mesh,), ("data",))
         backend = st.distributed(grid_axes=("data", None))
 
     p0, p1, c = grids(vp2_true)
@@ -171,4 +172,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -64,6 +64,7 @@ import json
 import os
 import tempfile
 import time
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -89,8 +90,12 @@ CACHE_ENV = "REPRO_AUTOTUNE_CACHE"
 #: increments once per (backend, fuse) configuration actually timed, and
 #: ``MEASURE_COUNT["pruned_candidates"]`` once per candidate the cost
 #: model pruned from the measured shortlist.  A warm cache (in-process
-#: or disk) serves without touching either.
+#: or disk) serves without touching either.  ``MEASURE_COUNT["failed"]``
+#: counts candidates whose run raised; they score ``inf``, and the
+#: exceptions are kept in ``MEASURE_FAILURES`` as ``(backend, exception)``
+#: so a kernel the compiler refuses is visible, not silently outranked.
 MEASURE_COUNT: collections.Counter = collections.Counter()
+MEASURE_FAILURES: List[Tuple[object, BaseException]] = []
 
 
 def clear_cache() -> None:
@@ -102,6 +107,15 @@ def reset_measure_count() -> None:
     """Zero the ``MEASURE_COUNT`` telemetry counters (tests use this to
     assert exactly how many candidates a tune() call measured/pruned)."""
     MEASURE_COUNT.clear()
+    MEASURE_FAILURES.clear()
+
+
+def _failed(backend, exc: Exception) -> float:
+    """Record a candidate whose run raised; its score is ``inf``."""
+    MEASURE_COUNT["failed"] += 1
+    MEASURE_FAILURES.append((backend, exc))
+    warnings.warn(f"autotune candidate {backend} failed: {exc!r}")
+    return float("inf")
 
 
 def shape_bucket(shape: Sequence[int]) -> Tuple[int, ...]:
@@ -358,6 +372,16 @@ def default_space(ndim: int, interior: Sequence[int]) -> List[st.Backend]:
     return out
 
 
+def _temporal(b, ndim):
+    """``b`` with the template and memory type its ``time_block>1`` kernel
+    really runs, so candidates that build one program dedup to one."""
+    if b.kind != "pallas" or b.time_block <= 1:
+        return b
+    from repro.kernels.stencil import codegen as _codegen
+    t, m = _codegen.temporal_body(b.template, b.mem_type, ndim)
+    return dataclasses.replace(b, template=t, mem_type=m)
+
+
 def _normalize_space(space, ndim, interior, swap, steps, fuse_space,
                      time_block_space=(1,)):
     """Expand the search space into (backend, fuse_steps) candidates.
@@ -385,7 +409,8 @@ def _normalize_space(space, ndim, interior, swap, steps, fuse_space,
             b, f = entry
             # without a swap pair only single applications are measured, so
             # a requested window size would be reported but never timed
-            cands.append((b, _norm_fuse(f) if swap is not None else 1))
+            cands.append((_temporal(b, ndim),
+                          _norm_fuse(f) if swap is not None else 1))
         elif swap is not None:
             backends = [entry]
             if entry.kind == "pallas":
@@ -395,8 +420,9 @@ def _normalize_space(space, ndim, interior, swap, steps, fuse_space,
                 tbs = dict.fromkeys(
                     [int(getattr(entry, "time_block", 1) or 1)]
                     + [int(tb) for tb in time_block_space])
-                backends = [dataclasses.replace(entry, time_block=tb)
-                            for tb in tbs]
+                backends = [_temporal(dataclasses.replace(entry,
+                                                          time_block=tb),
+                                      ndim) for tb in tbs]
             for b in backends:
                 for f in fuse_space:
                     cands.append((b, _norm_fuse(f)))
@@ -426,8 +452,8 @@ def _measure(kernel: st.Kernel, grids: Dict[str, st.grid], backend,
     args = tuple(gs.values())
     try:
         run(tgt)(*args)                      # warmup: codegen + compile
-    except Exception:
-        return float("inf")
+    except Exception as e:                   # noqa: BLE001 — scored inf
+        return _failed(backend, e)
     times = []
     for _ in range(iters):
         res = run(tgt)(*args)
@@ -448,8 +474,8 @@ def _measure_timeloop(kernel: st.Kernel, grids: Dict[str, st.grid],
     args = tuple(gs.values())
     try:
         run(tgt)(*args)                      # warmup: codegen + compile
-    except Exception:
-        return float("inf")
+    except Exception as e:                   # noqa: BLE001 — scored inf
+        return _failed(backend, e)
     times = []
     for _ in range(iters):
         times.append(run(tgt)(*args).value.seconds)

@@ -133,9 +133,10 @@ class Rate:
     overhead_s: float
 
 
-#: fallback rates when calibration is off or the probe fails.  Absolute
-#: values are deliberately coarse; the ranking-relevant property is the
-#: ~10³ bandwidth gap between compiled paths and interpret-mode pallas.
+#: fixed rates for ``CostModel(calibrate=False)``, the deterministic
+#: testing configuration.  Absolute values are deliberately coarse; the
+#: ranking-relevant property is the ~10³ bandwidth gap between compiled
+#: paths and interpret-mode pallas.
 DEFAULT_RATES: Dict[str, Rate] = {
     "xla": Rate(bytes_per_s=2e9, overhead_s=2e-4),
     "pallas": Rate(bytes_per_s=2e9, overhead_s=2e-4),
@@ -206,11 +207,10 @@ class CostModel:
                 # to disk so a later real-mesh call still probes
                 return DEFAULT_RATES["link"]
             if self.calibrate:
-                try:
-                    r = (self._probe_link(dtype, probe_mesh)
-                         if key == "link" else self._probe(key, dtype))
-                except Exception:
-                    r = DEFAULT_RATES[key]
+                # a failing probe raises: ranking against made-up rates
+                # for a device that was never measured would hide it
+                r = (self._probe_link(dtype, probe_mesh)
+                     if key == "link" else self._probe(key, dtype))
             else:
                 r = DEFAULT_RATES[key]
             self._rates[rk] = r
@@ -285,7 +285,6 @@ class CostModel:
         accounting ``HaloSpec.window_collective_bytes`` charges."""
         import time
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         devs = np.asarray(mesh.devices).reshape(-1)
@@ -301,7 +300,7 @@ class CostModel:
 
         def per_round_seconds(elems: int) -> float:
             x = jnp.zeros((n * elems,), dtype)
-            f = jax.jit(shard_map(ring_fn, mesh=ring,
+            f = jax.jit(jax.shard_map(ring_fn, mesh=ring,
                                   in_specs=P("ring"), out_specs=P("ring")))
             f(x).block_until_ready()          # compile + warm the path
             best = float("inf")

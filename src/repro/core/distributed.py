@@ -41,7 +41,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import analysis, ir, lowering
 from . import halo as _halo
@@ -203,11 +202,11 @@ def lower_distributed(kernel: ir.StencilIR,
         out = inner_full(padded, scalars)
         return {g: crop(out[g], g) for g in out_grids}
 
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         sharded_step, mesh=mesh,
         in_specs=({g: specs for g in all_grids}, P()),
         out_specs={g: specs for g in out_grids},
-        check_rep=False)
+        check_vma=False)
 
     jitted = jax.jit(shmapped)
 
@@ -340,11 +339,11 @@ def _lower_time_skewed(kernel, info, interior_shape, backend, mesh,
                 newer: crop(padded[newer], newer)}
 
     specs = P(*grid_axes)
-    shmapped = shard_map(
+    shmapped = jax.shard_map(
         sharded_k_steps, mesh=mesh,
         in_specs=({g: specs for g in all_grids}, P()),
         out_specs={swap[0]: specs, swap[1]: specs},
-        check_rep=False)
+        check_vma=False)
     jitted = jax.jit(shmapped)
 
     def fn(arrays, scalars):
@@ -631,12 +630,12 @@ def lower_distributed_window(kernel: ir.StencilIR,
             return lax.fori_loop(0, window, body, carry)
 
         mask_spec = P(None, *grid_axes)
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             sharded_body, mesh=mesh,
             in_specs=({g: gspec for g in all_grids}, P(), mask_spec,
                       P(), P()),
             out_specs={older: gspec, newer: gspec},
-            check_rep=False)
+            check_vma=False)
     else:
         (m_groups, _), = groups[:1]
         rem = groups[1] if len(groups) > 1 else None
@@ -666,11 +665,11 @@ def lower_distributed_window(kernel: ir.StencilIR,
                 carry = run_group(carry, pcoeffs, zcoeffs, scalars, rem_fns)
             return carry
 
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             sharded_body, mesh=mesh,
             in_specs=({g: gspec for g in all_grids}, P()),
             out_specs={older: gspec, newer: gspec},
-            check_rep=False)
+            check_vma=False)
 
     jitted = jax.jit(shmapped)
 
@@ -694,25 +693,25 @@ def lower_distributed_window(kernel: ir.StencilIR,
                 d_in, d_scal = vjp_fn(dict(cot))
                 return d_in, _psum_scal(d_scal)
 
-            bwd_shmapped = shard_map(
+            bwd_shmapped = jax.shard_map(
                 sharded_adjoint, mesh=mesh,
                 in_specs=({g: gspec for g in all_grids},
                           {older: gspec, newer: gspec}, P(),
                           P(None, *grid_axes), P(), P()),
                 out_specs=({g: gspec for g in all_grids}, P()),
-                check_rep=False)
+                check_vma=False)
         else:
             def sharded_adjoint(local_in, cot, scalars):
                 _, vjp_fn = jax.vjp(sharded_body, local_in, scalars)
                 d_in, d_scal = vjp_fn(dict(cot))
                 return d_in, _psum_scal(d_scal)
 
-            bwd_shmapped = shard_map(
+            bwd_shmapped = jax.shard_map(
                 sharded_adjoint, mesh=mesh,
                 in_specs=({g: gspec for g in all_grids},
                           {older: gspec, newer: gspec}, P()),
                 out_specs=({g: gspec for g in all_grids}, P()),
-                check_rep=False)
+                check_vma=False)
         bwd_jitted = jax.jit(bwd_shmapped)
 
     if differentiable and not masked:

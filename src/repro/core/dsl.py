@@ -22,9 +22,9 @@ Usage mirrors paper Listing 1::
 
 Constructs: ``kernel``, ``target``, ``map``, ``launch``, ``at``/``at.set``
 (inside kernels), ``grid``.  Backends: ``xla`` (pure-jnp/XLA), ``pallas``
-(TPU Pallas codegen; ``interpret=True`` on CPU), ``distributed`` (shard_map
-domain decomposition), plus a ``cuda`` compatibility alias so paper Listing 1
-runs verbatim.
+(TPU Pallas codegen, compiled by Mosaic on a TPU and interpreted where
+there is none), ``distributed`` (shard_map domain decomposition), plus a
+``cuda`` compatibility alias so paper Listing 1 runs verbatim.
 """
 from __future__ import annotations
 
@@ -36,6 +36,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro import kernels as _kernels
 
 from . import analysis as _analysis
 from . import frontend as _frontend
@@ -289,7 +291,11 @@ class pallas(Backend):
     streaming-dim storage for 2.5D templates ('registers' → unrolled VREG
     window, 'vmem' → VMEM scratch window, None → shape-directed default:
     star→registers, box→vmem, mirroring the paper's auto choice);
-    ``interpret`` runs the kernel body in Python on CPU for validation.
+    ``interpret`` runs the kernel body in the Pallas interpreter.  It
+    follows the platform when left ``None`` — compiled Mosaic on a TPU,
+    the interpreter where JAX finds no TPU — and the resolved value is
+    stored, so it keys every cache.  An explicit ``interpret=False``
+    without a TPU raises when the kernel is run.
 
     ``time_block=k`` enables in-kernel temporal blocking on the fused
     time-loop path (``st.timeloop``): each kernel invocation fetches a
@@ -306,10 +312,12 @@ class pallas(Backend):
     block: Optional[Tuple[int, ...]] = None
     mem_type: Optional[str] = None
     prefetch: bool = False
-    interpret: bool = True  # CPU container: interpret by default
+    interpret: Optional[bool] = None
     time_block: int = 1
 
     def __post_init__(self):
+        if self.interpret is None:
+            object.__setattr__(self, "interpret", not _kernels.on_tpu())
         if self.template not in ("gmem", "smem", "f4", "shift", "unroll", "semi"):
             raise ValueError(f"unknown template {self.template!r}")
         if int(self.time_block) < 1:
@@ -724,7 +732,7 @@ def differentiable_timeloop(k: Kernel, *args,
         fn = st.differentiable_timeloop(
             k, u, v, c, dt, steps=200, swap=("v", "u"),
             backend=st.distributed(grid_axes=("data", None)),
-            mesh=jax.make_mesh((8,), ("data",)))
+            mesh=make_mesh((8,), ("data",)))  # repro.launch.mesh
         value, grads = jax.value_and_grad(
             lambda a: jnp.sum(fn(a)["v"] ** 2))(fn.arrays)
     """
@@ -789,6 +797,8 @@ def _build_callable(k: Kernel, backend: Backend, grids: Dict[str, grid], region)
         jitted = jax.jit(fn)
     elif backend.kind == "pallas":
         from repro.kernels.stencil import codegen as _codegen
+        if not backend.interpret:
+            _kernels.require_tpu()
         fn = _codegen.lower_pallas(k.ir, halos, interior, region, backend)
         jitted = jax.jit(fn)
     elif backend.kind == "distributed":
@@ -802,28 +812,20 @@ def _build_callable(k: Kernel, backend: Backend, grids: Dict[str, grid], region)
     else:
         raise ValueError(backend.kind)
 
-    # explicit AOT compile so the profiler separates comp from kernel time
-    abstract_arrays = {n: jax.ShapeDtypeStruct(g.data.shape, g.dtype)
+    # explicit AOT compile so the profiler separates comp from kernel time;
+    # scalars are always passed as f32, so the signature is fixed
+    abstract_arrays = {n: jax.ShapeDtypeStruct(g.data.shape, g.data.dtype)
                        for n, g in grids.items()}
     abstract_scalars = {n: jax.ShapeDtypeStruct((), jnp.float32)
                         for n, _ in k.ir.scalar_params}
     t0 = time.perf_counter()
-    try:
-        compiled = jitted.lower(abstract_arrays, abstract_scalars).compile()
-        _CTX.add("comp", time.perf_counter() - t0)
+    compiled = jitted.lower(abstract_arrays, abstract_scalars).compile()
+    _CTX.add("comp", time.perf_counter() - t0)
 
-        def run(arrays, scalars):
-            scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
-            return compiled(arrays, scal)
-        return run
-    except Exception:
-        # fall back to on-demand jit (e.g. scalar dtype mismatch)
-        _CTX.add("comp", time.perf_counter() - t0)
-
-        def run(arrays, scalars):
-            scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
-            return jitted(arrays, scal)
-        return run
+    def run(arrays, scalars):
+        scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
+        return compiled(arrays, scal)
+    return run
 
 
 # --------------------------------------------------------------------------

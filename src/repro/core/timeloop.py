@@ -157,27 +157,19 @@ def _rotate(arrays: Dict[str, jnp.ndarray], swap) -> Dict[str, jnp.ndarray]:
     return out
 
 
-def _under_trace() -> bool:
-    """True while some jax transformation is tracing — i.e. the engine is
-    being driven from inside someone else's program (the adjoint replay,
-    a user jit around ``run``)."""
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:      # moved/renamed across jax versions
-        return False
-
-
-def _donate_ok(differentiable: bool = False) -> bool:
-    """Whether fused-window programs may donate their input buffers.
+def _donate_ok(differentiable: bool = False, arrays=None) -> bool:
+    """Whether a fused-window program may donate the input buffers
+    ``arrays`` (any pytree).
 
     Donation is gated by backend (CPU jit does not implement it — warns
     and copies) AND by differentiation: a donated window input is dead
     after the call, so it cannot be saved as a VJP residual or replayed
     from a checkpoint — the backward pass would read freed buffers.  Both
-    an explicit ``differentiable=True`` engine flag and trace detection
-    (the window being built while another transform is tracing, as the
-    adjoint's forward/replay passes do) disable donation."""
-    if differentiable or _under_trace():
+    an explicit ``differentiable=True`` engine flag and traced inputs (the
+    window driven from inside another transform, as the adjoint's
+    forward/replay passes or a user's ``jit`` do) disable donation."""
+    if differentiable or any(isinstance(a, jax.core.Tracer)
+                             for a in jax.tree.leaves(arrays)):
         return False
     return jax.default_backend() in ("tpu", "gpu")
 
@@ -248,16 +240,19 @@ class TimeloopEngine:
         if self._profile_cb is not None:
             self._profile_cb(phase, dt)
 
-    def _window(self, kw: int, masked: bool = False) -> Callable:
+    def _window(self, kw: int, masked: bool = False,
+                donate: bool = False) -> Callable:
         """Compiled fused program for a window of ``kw`` steps.
 
         ``masked=True`` (batched xla only) selects the serving variant with
-        per-scenario spatial masks and step budgets."""
-        fn = self._windows.get((kw, masked))
+        per-scenario spatial masks and step budgets; ``donate=True`` (see
+        ``_donate_ok``) lets the program reuse its first argument's
+        buffers."""
+        fn = self._windows.get((kw, masked, donate))
         if fn is not None:
             return fn
         t0 = time.perf_counter()
-        donate = (0,) if _donate_ok(self.differentiable) else ()
+        donate = (0,) if donate else ()
         if masked:
             if self.backend.kind not in ("xla", "distributed") \
                     or not self.batch:
@@ -334,7 +329,7 @@ class TimeloopEngine:
                 self.swap, kw, batch=self.batch,
                 differentiable=self.differentiable)
         self._add("comp", time.perf_counter() - t0)
-        self._windows[(kw, masked)] = fn
+        self._windows[(kw, masked, bool(donate))] = fn
         return fn
 
     def window_for(self, steps: int, fuse_steps: Optional[int] = None) -> int:
@@ -407,6 +402,9 @@ class TimeloopEngine:
         Returns a NEW dict of final buffers (same keys/shapes as
         ``arrays``); window programs are compiled once per (window, mask)
         signature and cached on the engine."""
+        if self.backend.kind == "pallas" and not self.backend.interpret:
+            from repro import kernels as _kernels
+            _kernels.require_tpu()
         fuse = self.window_for(steps, fuse_steps)
         arrays = dict(arrays)
         if self.batch:
@@ -452,7 +450,9 @@ class TimeloopEngine:
             kw = min(fuse, steps - t)
             t0 = time.perf_counter()
             if masked:
-                arrays = self._window(kw, masked=True)(
+                arrays = self._window(
+                    kw, masked=True,
+                    donate=_donate_ok(self.differentiable, arrays))(
                     arrays, scal, mask, jnp.int32(t), limits)
             else:
                 arrays = self._run_window(arrays, scal, kw)
@@ -464,8 +464,9 @@ class TimeloopEngine:
         return arrays
 
     def _run_window(self, arrays, scal, kw):
+        donate = _donate_ok(self.differentiable, arrays)
         if self.backend.kind == "xla":
-            return self._window(kw)(arrays, scal)
+            return self._window(kw, donate=donate)(arrays, scal)
         if self.backend.kind == "pallas":
             plan = self._plan
             t0 = time.perf_counter()
@@ -477,7 +478,7 @@ class TimeloopEngine:
                 padded = plan.to_padded(arrays)     # ONE pad/grid/window
             self._add("layout", time.perf_counter() - t0)
             plan.count_window(kw, batch=max(1, self.batch))  # modeled HBM
-            padded = self._window(kw)(padded, scal)
+            padded = self._window(kw, donate=donate)(padded, scal)
             # the device program rotated padded buffers kw times; apply the
             # same parity to the full host arrays so halos travel with
             # their buffers, then write the padded interiors back
@@ -488,7 +489,7 @@ class TimeloopEngine:
             return plan.from_padded(padded, arrays)
         # distributed: one program advances the whole window (exchange
         # groups + remainder + every leapfrog rotation happen in-program)
-        return self._window(kw)(arrays, scal)
+        return self._window(kw, donate=donate)(arrays, scal)
 
 
 def run_timeloop(kernel: _ir.StencilIR,

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 
 def _kernel(prev_ref, cur_ref, w_ref, out_ref, *, cw: int, bt: int):
@@ -33,7 +32,7 @@ def _kernel(prev_ref, cur_ref, w_ref, out_ref, *, cw: int, bt: int):
 
 def causal_conv1d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
                          block_t: int = 128, block_w: int = 128,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool) -> jnp.ndarray:
     """x: [B, T, W]; w: [cw, W] → causal depthwise conv, same length
     (zero history)."""
     B, T, W = x.shape
@@ -59,7 +58,7 @@ def causal_conv1d_pallas(x: jnp.ndarray, w: jnp.ndarray, *,
         out_shape=jax.ShapeDtypeStruct((B, Tp, Wp), x.dtype),
         interpret=interpret,
         name="causal_conv1d",
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
     )(xp, xp, wp)   # padded array feeds both the prev- and cur-block refs
     return out[:, :T, :W]

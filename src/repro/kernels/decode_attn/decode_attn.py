@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -67,7 +66,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def decode_attention_pallas(q, k, v, lengths, *, block_s: int = 512,
-                            interpret: bool = True):
+                            interpret: bool):
     """q: [B,H,hd]; k,v: [B,S,K,hd]; lengths: [B] → [B,H,hd]."""
     B, H, hd = q.shape
     S, K = k.shape[1], k.shape[2]
@@ -99,7 +98,7 @@ def decode_attention_pallas(q, k, v, lengths, *, block_s: int = 512,
         ],
         interpret=interpret,
         name="flash_decode_attention",
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(lengths, qg, k, v)
     return out.reshape(B, H, hd)

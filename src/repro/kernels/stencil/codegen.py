@@ -40,9 +40,10 @@ Two execution modes share the template bodies:
     outside the true interior pass the old value through, so the grid halo
     survives across steps with no repacking).  Per-grid operands are
     deduplicated: each padded grid is passed once and fetched as a halo'd
-    window (``pl.Unblocked`` BlockSpec) instead of once per neighbor
-    delta.  With ``backend.time_block=k`` the kernel stage is *temporally
-    blocked* (``_make_body_temporal``): windows carry k·h-deep expanded
+    window (``pl.Element`` block dims, widened to the (8, 128) tile that
+    a TPU window DMA must keep) instead of once per neighbor delta.  With
+    ``backend.time_block=k`` the kernel stage is *temporally blocked*
+    (``_make_body_temporal``): windows carry k·h-deep expanded
     halos and one invocation advances k leapfrog steps in VMEM.  The
     k-step outputs are *double-buffered*: they alias dedicated
     destination operands instead of the read buffers, because the
@@ -75,7 +76,6 @@ from repro.core import analysis, ir, lowering
 DEFAULT_BLOCK = {2: (8, 128), 3: (8, 8, 128)}
 STREAM_BLOCK = {2: (16, 128), 3: (16, 8, 128)}
 
-from repro.kernels.pallas_compat import CompilerParams as _CompilerParams
 
 # counts eager ``jnp.pad`` layout conversions per grid name; the fused path
 # must show exactly one per grid per fusion window (tests/test_timeloop.py)
@@ -98,6 +98,52 @@ def reset_traffic_count() -> None:
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def _tile(ax: int, ndim: int) -> int:
+    """Alignment a VMEM window must keep on axis ``ax``: the (8, 128) f32
+    tile of the last two axes; leading axes are untiled."""
+    return (8, 128)[ax - ndim + 2] if ax >= ndim - 2 else 1
+
+
+#: Mosaic's scoped-VMEM limit on v5e when a kernel sets none ("limit
+#: 16.00M" in its refusals)
+_VMEM_DEFAULT = 16 << 20
+#: VMEM a kernel body takes beyond ``PallasPlan.vmem_bytes``: the compiler
+#: asked for 19.00 and 30.25 MiB for star3d4r 512³ and acoustic-ISO 256³ at
+#: ``time_block=4``, 1.69 MiB over the model in both; about twice that
+_VMEM_SLACK = 4 << 20
+
+
+def _vmem_limit(modeled: int) -> Optional[int]:
+    """``vmem_limit_bytes`` for a kernel whose buffers model ``modeled``
+    bytes: ``None`` (the compiler's default) while they fit in it with the
+    body's slack, else room for both.  Of the fused plans at full size only
+    3-D ``time_block=4`` needs it (``tests/test_chip_compile.py``)."""
+    need = modeled + _VMEM_SLACK
+    return None if need <= _VMEM_DEFAULT else _round_up(need, 1 << 20)
+
+
+def temporal_body(template: str, mem_type: Optional[str], ndim: int):
+    """``(template, mem_type)`` a ``time_block>1`` kernel really runs.
+
+    A 2-D sub-step computes its whole extended block at once whatever the
+    template (a 2-D plane is a sublane row, which Mosaic cannot load at a
+    dynamic unaligned offset).  A 3-D sub-step runs plane by plane over
+    the VMEM frames, so temporaries stay one plane big: the streaming
+    templates keep their own loop, and the blocked ones (gmem, smem, f4),
+    whose per-point arithmetic is the same, run the 'vmem' shift loop."""
+    if ndim == 2:
+        return "gmem", None
+    if template in ("shift", "unroll", "semi"):
+        return template, mem_type
+    return "shift", "vmem"
+
+
+def _window_crop(wa, wf, B):
+    """Static slice taking the logical window (halo ``wf``) out of a
+    fetched, tile-aligned window (halo ``wa >= wf``)."""
+    return tuple(slice(a - f, a + b + f) for a, f, b in zip(wa, wf, B))
 
 
 def choose_block(user_block, template: str, ndim: int, region_shape,
@@ -159,15 +205,6 @@ def _paste_slices(delta, B, hg, ht):
             src.append(slice(0, h))
             dst.append(slice(t + b, t + b + h))
     return tuple(src), tuple(dst)
-
-
-def _assemble_tile(read_block, g, deltas, B, hg, ht, dtype):
-    tile_shape = tuple(B[ax] + 2 * ht[ax] for ax in range(len(B)))
-    tile = jnp.zeros(tile_shape, dtype)
-    for d in deltas:
-        src, dst = _paste_slices(d, B, hg, ht)
-        tile = tile.at[dst].set(read_block(g, d)[src])
-    return tile
 
 
 # ---------------------------------------------------------------------------
@@ -293,13 +330,39 @@ def _stream_halo(kernel, spec, variant):
     return None, max((gh[g][0] for g in in_grids), default=0)
 
 
-def _stream_outputs(kernel, spec, tiles, scalars, *, variant: str,
+class _Tile:
+    """One grid's x-column tile, read plane by plane from a VMEM ref.
+
+    The tile has x-halo ``H`` and the grid's own y/z halo.  Its ``n``
+    planes map onto ref rows ``x0 + t - pad`` (``yz`` statically slices
+    the y/z extent); the ``pad = H - h_g`` planes on each x side beyond
+    the grid's own halo read as zero, and ``t`` is clamped into the tile
+    like an out-of-range ``dynamic_slice``.  Streaming bodies index refs
+    and never values, because Mosaic lowers a dynamic row load of a ref
+    but no ``dynamic_slice`` of a value."""
+
+    def __init__(self, ref, x0: int, yz: tuple, n: int, pad: int):
+        self.ref, self.x0, self.yz, self.n, self.pad = ref, x0, yz, n, pad
+
+    def plane(self, t):
+        t = jnp.clip(t, 0, self.n - 1)
+        if not self.pad:
+            return self.ref[(self.x0 + t,) + self.yz]
+        i = t - self.pad
+        rows = self.n - 2 * self.pad
+        v = self.ref[(self.x0 + jnp.clip(i, 0, rows - 1),) + self.yz]
+        return jnp.where(jnp.logical_and(i >= 0, i < rows), v,
+                         jnp.zeros_like(v))
+
+
+def _stream_outputs(kernel, spec, tiles, scalars, emit, *, variant: str,
                     mem_type: str, H: int, lin):
-    """Run the 2.5D streaming loop over per-grid x-column ``tiles`` (x-halo
-    ``H``, per-grid y/z halo) and return the output blocks (shape B), one
-    per ``spec['out_grids']`` entry.  Shared by the per-application bodies
-    (tiles assembled from neighbor-block refs) and the fused bodies (tiles
-    sliced straight from the halo'd input window)."""
+    """Run the 2.5D streaming loop over per-grid x-column ``tiles``
+    (:class:`_Tile`, x-halo ``H``, per-grid y/z halo) and hand every
+    finished output plane to ``emit(t, {grid: plane})`` (``t`` the plane's
+    x index in the block ``spec['B']``).  Shared by the per-application
+    bodies (tiles pasted from neighbor-block refs) and the fused bodies
+    (tiles read straight from the halo'd input window)."""
     B, gh, ndim = spec["B"], spec["gh"], spec["ndim"]
     out_grids, dtype = spec["out_grids"], spec["dtype"]
     in_grids = spec["in_grids"]
@@ -308,7 +371,7 @@ def _stream_outputs(kernel, spec, tiles, scalars, *, variant: str,
 
     def plane(g, t):
         """Input plane at tile-x index t, full y/z halo extent."""
-        return lax.dynamic_slice_in_dim(tiles[g], t, 1, axis=0)[0]
+        return tiles[g].plane(t)
 
     def center_yz(g, arr, offs_yz):
         h = gh[g][1:]
@@ -325,86 +388,77 @@ def _stream_outputs(kernel, spec, tiles, scalars, *, variant: str,
                                  tuple(offs[1:]))
             return tr
 
-        def step(t, carry):
+        zero = jnp.zeros(plane_shape, dtype)
+
+        def step(t, Ps):
             # Invariant: at start of step t, P[k] holds the partial sum
             # for output plane (t - 2H + k).  Input plane at tile-x
             # index t is region plane x_in = t - H; its term (g,offs=d)
             # contributes coeff(x_in - d) * u[x_in] to out plane
             # o = x_in - d (slot H - d, coeff-field tile idx t - d,
             # clamped reads only ever reach never-emitted planes).
-            Ps, outs = carry
-            newPs, newouts = [], []
-            for og, P, out in zip(out_grids, Ps, outs):
+            newPs, done = [], {}
+            for og, P in zip(out_grids, Ps):
+                P = list(P)
                 terms, const = lin[og]
                 for (g, offs, c) in terms:
                     d = offs[0]
                     cval = lowering.eval_expr(
                         c, field_read_at(t - d), scalars, {})
                     contrib = cval * center_yz(g, plane(g, t), offs[1:])
-                    P = P.at[H - d].add(contrib)
+                    P[H - d] = P[H - d] + contrib
                 cv = lowering.eval_expr(
                     const, field_read_at(t - H), scalars, {})
-                done = P[0] + cv
-                o = t - 2 * H
-                out = lax.cond(
-                    o >= 0,
-                    lambda out=out, done=done, o=o:
-                        lax.dynamic_update_slice_in_dim(
-                            out, done[None], o, axis=0),
-                    lambda out=out: out)
-                P = jnp.concatenate(
-                    [P[1:], jnp.zeros((1,) + plane_shape, dtype)], axis=0)
-                newPs.append(P)
-                newouts.append(out)
-            return tuple(newPs), tuple(newouts)
+                done[og] = jnp.broadcast_to(P[0] + cv, plane_shape)
+                newPs.append(tuple(P[1:]) + (zero,))
+            o = t - 2 * H
 
-        Ps0 = tuple(jnp.zeros((2 * H + 1,) + plane_shape, dtype)
-                    for _ in out_grids)
-        outs0 = tuple(jnp.zeros(B, dtype) for _ in out_grids)
-        _, outs = lax.fori_loop(0, bx + 2 * H, step, (Ps0, outs0))
-        return outs
+            @pl.when(o >= 0)
+            def _():
+                emit(o, done)
+            return tuple(newPs)
+
+        Ps0 = tuple((zero,) * (2 * H + 1) for _ in out_grids)
+        lax.fori_loop(0, bx + 2 * H, step, Ps0)
+        return
 
     # ---- shift / unroll ------------------------------------------------
     win_len = {g: 2 * gh[g][0] + 1 for g in in_grids}
 
     if mem_type == "vmem":
         # stream straight from the VMEM tile: taps = dynamic plane slices
-        def compute_plane(t):
+        def step(t, carry):
             def tap_read(g, offs):
                 # tile x index of region plane t+offs[0]: t + H + offs[0]
                 p = plane(g, t + H + offs[0])
                 return center_yz(g, p, offs[1:])
-            return _exec_statements(kernel, tap_read, scalars,
-                                    plane_shape, dtype)
+            emit(t, _exec_statements(kernel, tap_read, scalars,
+                                     plane_shape, dtype))
+            return carry
 
-        def step(t, outs):
-            env = compute_plane(t)
-            return tuple(
-                lax.dynamic_update_slice_in_dim(out, env[g][None], t, axis=0)
-                for g, out in zip(out_grids, outs))
-
-        outs0 = tuple(jnp.zeros(B, dtype) for _ in out_grids)
-        return lax.fori_loop(0, bx, step, outs0)
+        lax.fori_loop(0, bx, step, 0)
+        return
 
     # mem_type == 'registers': rolling loop-carried window per grid.
     # Invariant: after `advance` at step t, window slot k holds the
     # plane at region coord t - hg0 + k (tile-x index t - hg0 + k + H).
+    # shift carries the window as a tuple of planes (the rotation is a
+    # renaming); unroll rebuilds one stacked array by concatenation.
     def init_window(g):
         n = win_len[g]
         hg0 = gh[g][0]
-        planes = [jnp.zeros(tiles[g].shape[1:], dtype)]
+        planes = [jnp.zeros_like(plane(g, 0))]
         for k in range(1, n):
             planes.append(plane(g, H - hg0 + k - 1))
-        return jnp.stack(planes, axis=0)
+        return jnp.stack(planes, axis=0) if variant == "unroll" \
+            else tuple(planes)
 
     def advance(W, new_plane):
         if variant == "unroll":
             return jnp.concatenate([W[1:], new_plane[None]], axis=0)
-        W = jnp.roll(W, -1, axis=0)
-        return W.at[-1].set(new_plane)
+        return W[1:] + (new_plane,)
 
-    def step(t, carry):
-        Ws, outs = carry
+    def step(t, Ws):
         # newest slot holds region plane t + hg0 → tile-x index t+hg0+H
         Ws2 = tuple(advance(W, plane(g, t + gh[g][0] + H))
                     for g, W in zip(in_grids, Ws))
@@ -414,22 +468,21 @@ def _stream_outputs(kernel, spec, tiles, scalars, *, variant: str,
             slot = gh[g][0] + offs[0]
             return center_yz(g, W[slot], offs[1:])
 
-        env = _exec_statements(kernel, tap_read, scalars, plane_shape, dtype)
-        outs = tuple(
-            lax.dynamic_update_slice_in_dim(out, env[g][None], t, axis=0)
-            for g, out in zip(out_grids, outs))
-        return Ws2, outs
+        emit(t, _exec_statements(kernel, tap_read, scalars, plane_shape,
+                                 dtype))
+        return Ws2
 
-    Ws0 = tuple(init_window(g) for g in in_grids)
-    outs0 = tuple(jnp.zeros(B, dtype) for _ in out_grids)
-    _, outs = lax.fori_loop(0, bx, step, (Ws0, outs0))
-    return outs
+    lax.fori_loop(0, bx, step, tuple(init_window(g) for g in in_grids))
 
 
 def _make_body_streaming(kernel, info, spec, *, variant: str,
                          mem_type: str, prefetch: bool):
-    """shift / unroll / semi bodies: 2.5D streaming along axis 0."""
-    B, gh = spec["B"], spec["gh"]
+    """shift / unroll / semi bodies: 2.5D streaming along axis 0.  Each
+    grid's x-column tile (x-halo H, its own y/z halo) is pasted from the
+    neighbor-block refs into a zeroed VMEM scratch buffer, as the smem
+    template does; planes beyond the grid's halo stay zero, harmless for
+    the linear scatter."""
+    B, gh, ndim = spec["B"], spec["gh"], spec["ndim"]
     in_index, scal_names, out_grids, dtype = (
         spec["in_index"], spec["scal_names"], spec["out_grids"], spec["dtype"])
     in_grids = spec["in_grids"]
@@ -440,26 +493,38 @@ def _make_body_streaming(kernel, info, spec, *, variant: str,
         in_refs = refs[:n_in]
         scal_refs = refs[n_in:n_in + len(scal_names)]
         out_refs = refs[n_in + len(scal_names):n_in + len(scal_names) + len(out_grids)]
+        scratch = refs[n_in + len(scal_names) + len(out_grids):]
 
         scalars = {n: r[0, 0] for n, r in zip(scal_names, scal_refs)}
 
-        def read_block(g, d):
-            return in_refs[in_index[(g, d)]][...]
-
-        # assemble per-grid x-column tiles with x-halo H (>= per-grid halo;
-        # extra planes stay zero, harmless for the linear scatter)
         tiles = {}
-        for g in in_grids:
+        for sref, g in zip(scratch, in_grids):
             ht = (H,) + tuple(gh[g][1:])
-            tiles[g] = _assemble_tile(read_block, g, spec["deltas"][g],
-                                      B, gh[g], ht, dtype)
+            sref[...] = jnp.zeros(sref.shape, dtype)
+            for d in spec["deltas"][g]:
+                src, dst = _paste_slices(d, B, gh[g], ht)
+                sref[dst] = in_refs[in_index[(g, d)]][src]
+            tiles[g] = _Tile(sref, 0, (slice(None),) * (ndim - 1),
+                             B[0] + 2 * H, 0)
 
-        outs = _stream_outputs(kernel, spec, tiles, scalars, variant=variant,
-                               mem_type=mem_type, H=H, lin=lin)
-        for out, oref in zip(outs, out_refs):
-            oref[...] = out
+        def emit(t, env):
+            for g, oref in zip(out_grids, out_refs):
+                oref[pl.ds(t, 1)] = env[g][None]
+
+        _stream_outputs(kernel, spec, tiles, scalars, emit, variant=variant,
+                        mem_type=mem_type, H=H, lin=lin)
 
     return body
+
+
+def _stream_scratch(kernel, spec, variant, dtype):
+    """VMEM tile buffers of the per-application streaming body."""
+    B, gh, ndim = spec["B"], spec["gh"], spec["ndim"]
+    _, H = _stream_halo(kernel, spec, variant)
+    return [pltpu.VMEM((B[0] + 2 * H,) + tuple(B[ax] + 2 * gh[g][ax]
+                                               for ax in range(1, ndim)),
+                       dtype)
+            for g in spec["in_grids"]]
 
 
 # ---------------------------------------------------------------------------
@@ -471,8 +536,8 @@ def lower_pallas(kernel: ir.StencilIR,
                  region,
                  backend):
     """Build ``fn(arrays: dict, scalars: dict) -> dict`` running the kernel
-    through a generated Pallas TPU kernel (interpret=True executes the body
-    in Python on CPU)."""
+    through a generated Pallas TPU kernel (``backend.interpret`` runs the
+    body in the Pallas interpreter)."""
     info = analysis.analyze(kernel)
     ndim = kernel.ndim
     if ndim not in (2, 3):
@@ -553,7 +618,7 @@ def lower_pallas(kernel: ir.StencilIR,
             body = _make_body_streaming(kernel, info, spec_d,
                                         variant=template, mem_type=mem_type,
                                         prefetch=backend.prefetch)
-            scratch_shapes = []
+            scratch_shapes = _stream_scratch(kernel, spec_d, template, dtype)
 
         # ---- pad inputs: one extra block per side + halo placement -------
         ops = []
@@ -592,7 +657,7 @@ def lower_pallas(kernel: ir.StencilIR,
             scratch_shapes=scratch_shapes,
             interpret=backend.interpret,
             name=f"stencil_{kernel.name}_{template}",
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",) * ndim),
         )
         outs = call(*ops)
@@ -616,20 +681,52 @@ def lower_pallas(kernel: ir.StencilIR,
 # ---------------------------------------------------------------------------
 # fused time-loop path: one-time layout stage + per-step kernel stage
 # ---------------------------------------------------------------------------
-def _valid_mask(B, R, ndim, ext=None):
+def _block_origin(B):
+    """Interior coordinates of this program instance's block.  Read once
+    at the top of a body: interpret mode resolves ``program_id`` only
+    there, not inside the body's loops."""
+    return tuple(pl.program_id(ax) * b for ax, b in enumerate(B))
+
+
+def _valid_mask(origin, B, R, ext=None, plane=None):
     """Mask of positions that belong to the true interior, over the block
-    extended by ``ext`` per side (temporal sub-steps compute shrinking
-    shells that reach below coordinate 0 and past R; the block may also
-    overhang the interior when R is not a block multiple)."""
+    at ``origin`` (``_block_origin``) extended by ``ext`` per side
+    (temporal sub-steps compute shrinking shells that reach below
+    coordinate 0 and past R; the block may also overhang the interior
+    when R is not a block multiple).  ``plane=t`` gives the mask of
+    x-plane ``t`` of that extended block only."""
+    ndim = len(B)
     ext = ext or (0,) * ndim
-    shape = tuple(B[ax] + 2 * ext[ax] for ax in range(ndim))
+    first = 0 if plane is None else 1
+    shape = tuple(B[ax] + 2 * ext[ax] for ax in range(first, ndim))
     mask = None
     for ax in range(ndim):
-        coord = (pl.program_id(ax) * B[ax] - ext[ax]
-                 + lax.broadcasted_iota(jnp.int32, shape, ax))
+        coord = origin[ax] - ext[ax]
+        if ax < first:
+            coord = coord + plane
+        else:
+            coord = coord + lax.broadcasted_iota(jnp.int32, shape, ax - first)
         m = jnp.logical_and(coord >= 0, coord < R[ax])
         mask = m if mask is None else jnp.logical_and(mask, m)
-    return mask
+    return jnp.broadcast_to(mask, shape)
+
+
+def _window_tiles(spec, H, wins, ext):
+    """Streaming tiles over windows ``wins[g] = (ref, origin)``: the tile
+    of grid ``g`` covers the block extended by ``ext`` plus the grid's own
+    halo, read from the window whose logical (halo ``wf``) origin sits at
+    ``origin`` in the ref."""
+    B, gh, ndim, wf = spec["B"], spec["gh"], spec["ndim"], spec["wf"]
+    tiles = {}
+    for g in spec["in_grids"]:
+        ref, org = wins[g]
+        w, h = wf[g], gh[g]
+        lo = tuple(org[ax] + w[ax] - ext[ax] - h[ax] for ax in range(ndim))
+        yz = tuple(slice(lo[ax], lo[ax] + B[ax] + 2 * (ext[ax] + h[ax]))
+                   for ax in range(1, ndim))
+        tiles[g] = _Tile(ref, lo[0], yz, B[0] + 2 * ext[0] + 2 * H,
+                         H - h[0])
+    return tiles
 
 
 def _make_body_fused(kernel, info, spec, *, template: str, mem_type: str):
@@ -641,7 +738,7 @@ def _make_body_fused(kernel, info, spec, *, template: str, mem_type: str):
     opnd_index, scal_names, out_grids, dtype = (
         spec["opnd_index"], spec["scal_names"], spec["out_grids"],
         spec["dtype"])
-    in_grids = spec["in_grids"]
+    crop = spec["crop"]
     streaming = template in ("shift", "unroll", "semi")
     lin = H = None
     if streaming:
@@ -654,44 +751,46 @@ def _make_body_fused(kernel, info, spec, *, template: str, mem_type: str):
         out_refs = refs[n_in + len(scal_names):]
 
         scalars = {n: r[0, 0] for n, r in zip(scal_names, scal_refs)}
+        # outputs have center-only taps, so their window is the bare block
+        # (crop offset 0): the old value outside the interior is read there
+        old = {g: in_refs[opnd_index[g]] for g in out_grids}
+        origin = _block_origin(B)
+
+        if streaming:
+            wins = {g: (in_refs[opnd_index[g]],
+                        tuple(c.start for c in crop[g]))
+                    for g in spec["in_grids"]}
+            tiles = _window_tiles(spec, H, wins, (0,) * ndim)
+
+            def emit(t, env):
+                mask = _valid_mask(origin, B, R, plane=t)
+                for g, oref in zip(out_grids, out_refs):
+                    oref[pl.ds(t, 1)] = jnp.where(
+                        mask, env[g], old[g][pl.ds(t, 1)][0])[None]
+
+            _stream_outputs(kernel, spec, tiles, scalars, emit,
+                            variant=template, mem_type=mem_type, H=H,
+                            lin=lin)
+            return
+
         loaded: Dict = {}
 
         def win(g):
             if g not in loaded:
-                loaded[g] = in_refs[opnd_index[g]][...]
+                loaded[g] = in_refs[opnd_index[g]][crop[g]]
             return loaded[g]
 
-        if streaming:
-            # tiles sliced straight from the fetched window; zero-extend the
-            # x-halo to the streaming halo H, matching the per-application
-            # tile assembly (extra planes stay zero for the linear scatter)
-            tiles = {}
-            for g in in_grids:
-                w = win(g)
-                if H == gh[g][0]:
-                    tiles[g] = w
-                else:
-                    pad0 = H - gh[g][0]
-                    t = jnp.zeros((B[0] + 2 * H,) + w.shape[1:], dtype)
-                    tiles[g] = t.at[pad0:pad0 + w.shape[0]].set(w)
-            env_vals = _stream_outputs(kernel, spec, tiles, scalars,
-                                       variant=template, mem_type=mem_type,
-                                       H=H, lin=lin)
-            env = dict(zip(out_grids, env_vals))
-        else:
-            def tap_read(g, offs):
-                h = gh[g]
-                idx = tuple(slice(h[ax] + offs[ax], h[ax] + offs[ax] + B[ax])
-                            for ax in range(ndim))
-                return win(g)[idx]
+        def tap_read(g, offs):
+            h = gh[g]
+            idx = tuple(slice(h[ax] + offs[ax], h[ax] + offs[ax] + B[ax])
+                        for ax in range(ndim))
+            return win(g)[idx]
 
-            env = _exec_statements(kernel, tap_read, scalars, B, dtype)
-
-        mask = _valid_mask(B, R, ndim)
+        env = _exec_statements(kernel, tap_read, scalars, B, dtype)
+        mask = _valid_mask(origin, B, R)
         for g, oref in zip(out_grids, out_refs):
-            # outside the interior keep the old value (win(g) is the bare
-            # center block: fused mode requires center-only taps of outputs)
-            oref[...] = jnp.where(mask, env[g], win(g))
+            # outside the interior keep the old value
+            oref[...] = jnp.where(mask, env[g], old[g][...])
 
     return body
 
@@ -703,18 +802,20 @@ def _make_body_temporal(kernel, info, spec, *, template: str, mem_type: str,
     Pallas block, cf. ``distributed._lower_time_skewed`` at pod level).
 
     Each operand grid is fetched once as a window with an expanded halo
-    (``k·h`` for the swap pair, ``(k−1)·h + h_g`` for coefficient grids)
-    and kept as a VMEM-resident *frame*.  Sub-step ``j`` evaluates the
-    template body over the block extended by ``(k−1−j)·h`` per side — the
-    valid region shrinks by ``h`` per step, shells being recomputed
-    redundantly by neighboring blocks — and writes the leapfrog buffers
-    alternately (sub-step 0 → ``swap[0]``'s buffer, 1 → ``swap[1]``'s, …),
-    which is exactly the per-step write+rotate sequence expressed in buffer
-    space.  Outside the true interior every sub-step passes the frame's old
-    value through, so grid-halo cells keep their original values and feed
-    later sub-steps unchanged (per-step boundary semantics).  Only the
-    final ``B`` interior of each swap frame is written back — HBM sees one
-    read and one write per grid per ``k`` steps.
+    (``k·h`` for the swap pair, ``(k−1)·h + h_g`` for coefficient grids).
+    The swap pair's windows are copied into VMEM scratch *frames*, which
+    the sub-steps update in place; coefficient grids are read straight
+    from their fetched windows.  Sub-step ``j`` evaluates the template
+    body over the block extended by ``(k−1−j)·h`` per side — the valid
+    region shrinks by ``h`` per step, shells being recomputed redundantly
+    by neighboring blocks — and writes the leapfrog buffers alternately
+    (sub-step 0 → ``swap[0]``'s buffer, 1 → ``swap[1]``'s, …), which is
+    exactly the per-step write+rotate sequence expressed in buffer space.
+    Outside the true interior every sub-step passes the frame's old value
+    through, so grid-halo cells keep their original values and feed later
+    sub-steps unchanged (per-step boundary semantics).  Only the final
+    ``B`` interior of each swap frame is written back — HBM sees one read
+    and one write per grid per ``k`` steps.
 
     The write-back is *double-buffered*: outputs alias dedicated
     destination operands (``refs[n_in:n_in+len(step_out)]``, never read
@@ -728,15 +829,12 @@ def _make_body_temporal(kernel, info, spec, *, template: str, mem_type: str,
     B, gh, ndim, R = spec["B"], spec["gh"], spec["ndim"], spec["R"]
     opnd_index, scal_names, dtype = (
         spec["opnd_index"], spec["scal_names"], spec["dtype"])
-    in_grids = spec["in_grids"]
+    in_grids, crop = spec["in_grids"], spec["crop"]
     wf, hvec = spec["wf"], spec["hvec"]
     step_out = spec["step_out_grids"]          # (written, other) buffers
     k = time_block
     written, other = swap
-    streaming = template in ("shift", "unroll", "semi")
-    lin = H = None
-    if streaming:
-        lin, H = _stream_halo(kernel, spec, template)
+    lin, H = _stream_halo(kernel, spec, template)
 
     def body(*refs):
         n_in = len(opnd_index)
@@ -745,10 +843,19 @@ def _make_body_temporal(kernel, info, spec, *, template: str, mem_type: str,
         # read windows and the scalars; their values are never read
         n_dst = len(step_out)
         scal_refs = refs[n_in + n_dst:n_in + n_dst + len(scal_names)]
-        out_refs = refs[n_in + n_dst + len(scal_names):]
+        n_out = len(step_out)
+        out_refs = refs[n_in + n_dst + len(scal_names):
+                        n_in + n_dst + len(scal_names) + n_out]
+        scratch = refs[n_in + n_dst + len(scal_names) + n_out:]
 
         scalars = {n: r[0, 0] for n, r in zip(scal_names, scal_refs)}
-        frames = {g: in_refs[i][...] for g, i in opnd_index.items()}
+        origin = _block_origin(B)
+        # frame of each buffer: (ref, origin of its logical window)
+        frames = {g: (in_refs[i], tuple(c.start for c in crop[g]))
+                  for g, i in opnd_index.items()}
+        for g, sref in zip(step_out, scratch):
+            sref[...] = in_refs[opnd_index[g]][crop[g]]
+            frames[g] = (sref, (0,) * ndim)
 
         for j in range(k):
             ext = tuple((k - 1 - j) * hvec[ax] for ax in range(ndim))
@@ -756,55 +863,60 @@ def _make_body_temporal(kernel, info, spec, *, template: str, mem_type: str,
             # leapfrog in buffer space: IR names ↔ buffers alternate
             nm = {written: written, other: other} if j % 2 == 0 \
                 else {written: other, other: written}
+            tgt_ref, _ = frames[nm[written]]
+            w = wf[nm[written]]
+            lo = tuple(w[ax] - ext[ax] for ax in range(ndim))
 
-            if streaming:
-                tiles = {}
-                for g in in_grids:
-                    buf = nm.get(g, g)
-                    w, h = wf[buf], gh[g]
-                    tile = frames[buf][tuple(
-                        slice(w[ax] - ext[ax] - h[ax],
-                              w[ax] + B[ax] + ext[ax] + h[ax])
-                        for ax in range(ndim))]
-                    if H > h[0]:
-                        # zero-extend the x-halo to the streaming halo H,
-                        # matching the single-step fused body
-                        pad0 = H - h[0]
-                        t = jnp.zeros((S[0] + 2 * H,) + tile.shape[1:], dtype)
-                        tile = t.at[pad0:pad0 + tile.shape[0]].set(tile)
-                    tiles[g] = tile
-                env_vals = _stream_outputs(kernel, dict(spec, B=S), tiles,
-                                           scalars, variant=template,
-                                           mem_type=mem_type, H=H, lin=lin)
-                val = env_vals[0]
-            else:
+            if ndim == 2:
+                # 2-D planes are rows on the sublane axis, which Mosaic
+                # cannot load at a dynamic unaligned offset; a 2-D block
+                # is small, so the sub-step computes all of it at once
                 def tap_read(g, offs, ext=ext, S=S, nm=nm):
-                    buf = nm.get(g, g)
-                    w = wf[buf]
-                    idx = tuple(
-                        slice(w[ax] - ext[ax] + offs[ax],
-                              w[ax] - ext[ax] + offs[ax] + S[ax])
-                        for ax in range(ndim))
-                    return frames[buf][idx]
+                    ref, org = frames[nm.get(g, g)]
+                    w = wf[nm.get(g, g)]
+                    return ref[tuple(
+                        slice(org[ax] + w[ax] - ext[ax] + offs[ax],
+                              org[ax] + w[ax] - ext[ax] + offs[ax] + S[ax])
+                        for ax in range(ndim))]
 
-                env = _exec_statements(kernel, tap_read, scalars, S, dtype)
-                val = env[written]
+                val = _exec_statements(kernel, tap_read, scalars, S,
+                                       dtype)[written]
+                region = tuple(slice(lo[ax], lo[ax] + S[ax])
+                               for ax in range(ndim))
+                tgt_ref[region] = jnp.where(
+                    _valid_mask(origin, B, R, ext), val, tgt_ref[region])
+                continue
 
-            tgt = nm[written]
-            w = wf[tgt]
-            region = tuple(slice(w[ax] - ext[ax], w[ax] + B[ax] + ext[ax])
-                           for ax in range(ndim))
+            # planes are emitted in place: outputs have center-only taps,
+            # so plane t of the target is read before it is written and
+            # never read again in this sub-step
+            wins = {}
+            for g in in_grids:
+                ref, org = frames[nm.get(g, g)]
+                # the tile geometry follows grid g, its data the buffer
+                # the name is bound to in this sub-step
+                wins[g] = (ref, tuple(org[ax] + wf[nm.get(g, g)][ax]
+                                      - wf[g][ax] for ax in range(ndim)))
+            tiles = _window_tiles(spec, H, wins, ext)
+
             # outside the true interior the buffer keeps its original
             # (grid-halo) value — re-imposed every sub-step so shells never
             # leak boundary garbage into later sub-steps
-            mask = _valid_mask(B, R, ndim, ext)
-            frames[tgt] = frames[tgt].at[region].set(
-                jnp.where(mask, val, frames[tgt][region]))
+            def emit(t, env, lo=lo, ext=ext, S=S, tgt_ref=tgt_ref):
+                idx = (lo[0] + t,) + tuple(
+                    slice(lo[ax], lo[ax] + S[ax]) for ax in range(1, ndim))
+                mask = _valid_mask(origin, B, R, ext, plane=t)
+                tgt_ref[idx] = jnp.where(mask, env[written], tgt_ref[idx])
+
+            _stream_outputs(kernel, dict(spec, B=S), tiles, scalars, emit,
+                            variant=template, mem_type=mem_type, H=H,
+                            lin=lin)
 
         for g, oref in zip(step_out, out_refs):
+            ref, _ = frames[g]
             w = wf[g]
-            oref[...] = frames[g][tuple(slice(w[ax], w[ax] + B[ax])
-                                        for ax in range(ndim))]
+            oref[...] = ref[tuple(slice(w[ax], w[ax] + B[ax])
+                                  for ax in range(ndim))]
 
     return body
 
@@ -847,11 +959,13 @@ class PallasPlan:
         ndim = kernel.ndim
         if ndim not in (2, 3):
             raise ValueError("pallas backend supports 2D and 3D stencils")
-        template = backend.template
+        template, mem_type = backend.template, backend.mem_type
         R = tuple(interior_shape)
         k = int(getattr(backend, "time_block", 1) or 1)
         if k < 1:
             raise ValueError("time_block must be >= 1")
+        if k > 1:
+            template, mem_type = temporal_body(template, mem_type, ndim)
         hvec = tuple(info.halo) if info.halo else (0,) * ndim
         in_grids = info.input_grids
         out_grids = info.output_grids
@@ -903,7 +1017,6 @@ class PallasPlan:
         if template == "f4" and (B[-1] % 128 or B[-2] % 8):
             raise ValueError("f4 template requires lane-aligned blocks "
                              "(last dim %128, 2nd-last %8)")
-        mem_type = backend.mem_type
         if mem_type is None:
             mem_type = "registers" if info.shape in ("star", "point") \
                 else "vmem"
@@ -928,10 +1041,24 @@ class PallasPlan:
         padded_shape = tuple((nb[ax] + 2) * B[ax] for ax in range(ndim))
         scal_names = [n for n, _ in kernel.scalar_params]
 
+        # fetched window halo: the DMA that brings a window into VMEM must
+        # start and end on the (8, 128) tile of the last two axes, so where
+        # the block is a tile multiple the logical halo ``wf`` is rounded up
+        # to the tile (wa <= B still holds) and the body crops the logical
+        # window back out of the fetched one (``_window_crop``).  A user's
+        # block that is not a tile multiple keeps the exact window: the
+        # interpreter runs it, the TPU lowering refuses it.
+        ts = tuple(_tile(ax, ndim) if B[ax] % _tile(ax, ndim) == 0 else 1
+                   for ax in range(ndim))
+        wa = {g: tuple(_round_up(wf[g][ax], ts[ax]) for ax in range(ndim))
+              for g in opnd_grids}
+
         def _window_map(w):
+            # (g·(B/t) + (B−w)/t)·t: the tile factor t outermost, the form
+            # in which Mosaic proves the window start tile-aligned
             def imap(*gi):
-                return tuple(gi[ax] * B[ax] + B[ax] - w[ax]
-                             for ax in range(ndim))
+                return tuple((gi[ax] * (B[ax] // t) + (B[ax] - w[ax]) // t)
+                             * t for ax, t in enumerate(ts))
             return imap
 
         # per-invocation outputs: with time_block > 1 both swap buffers are
@@ -940,10 +1067,10 @@ class PallasPlan:
 
         in_specs = []
         for g in opnd_grids:
-            w = wf[g]
+            w = wa[g]
             in_specs.append(pl.BlockSpec(
-                tuple(B[ax] + 2 * w[ax] for ax in range(ndim)),
-                _window_map(w), indexing_mode=pl.Unblocked()))
+                tuple(pl.Element(B[ax] + 2 * w[ax]) for ax in range(ndim)),
+                _window_map(w)))
         if k > 1:
             # double-buffered outputs: the k·h-expanded windows reach into
             # neighboring blocks' output interiors, and the TPU grid runs
@@ -972,7 +1099,7 @@ class PallasPlan:
         self.template, self.mem_type = template, mem_type
         self.ndim, self.R, self.B, self.nb = ndim, R, B, nb
         self.gh, self.hw, self.swap = gh, hw, swap
-        self.time_block, self.hvec, self.wf = k, hvec, wf
+        self.time_block, self.hvec, self.wf, self.wa = k, hvec, wf, wa
         self.in_grids, self.out_grids = in_grids, out_grids
         self.step_out_grids = step_out
         self.opnd_grids, self.scal_names = opnd_grids, scal_names
@@ -1017,12 +1144,27 @@ class PallasPlan:
         (they amortize over the window length, which the plan does not
         know)."""
         nblocks = math.prod(self.nb)
-        read = sum(math.prod(self.B[ax] + 2 * self.wf[g][ax]
+        read = sum(math.prod(self.B[ax] + 2 * self.wa[g][ax]
                              for ax in range(self.ndim))
                    for g in self.opnd_grids)
         read += self._dest_fetches * math.prod(self.B)
         write = len(self.step_out_grids) * math.prod(self.B)
         return nblocks * (read + write) * itemsize / self.time_block
+
+    def vmem_bytes(self, itemsize: int = 4) -> int:
+        """Modeled VMEM of one kernel invocation: the pipeline holds two
+        copies of every fetched window, destination block and output
+        block, plus the ``time_block>1`` frames."""
+        blk = math.prod(self.B)
+        pipelined = sum(math.prod(self.B[ax] + 2 * self.wa[g][ax]
+                                  for ax in range(self.ndim))
+                        for g in self.opnd_grids)
+        pipelined += (self._dest_fetches + len(self.step_out_grids)) * blk
+        frames = sum(math.prod(self.B[ax] + 2 * self.wf[g][ax]
+                               for ax in range(self.ndim))
+                     for g in self.step_out_grids) \
+            if self.time_block > 1 else 0
+        return (2 * pipelined + frames) * itemsize
 
     def layout_bytes_per_window(self, itemsize: int = 4) -> float:
         """Modeled HBM bytes of the one-time per-fusion-window costs that
@@ -1093,6 +1235,8 @@ class PallasPlan:
                         scal_names=self.scal_names,
                         out_grids=self.out_grids, in_grids=self.in_grids,
                         wf=self.wf, hvec=self.hvec,
+                        crop={g: _window_crop(self.wa[g], self.wf[g], self.B)
+                              for g in self.opnd_grids},
                         step_out_grids=self.step_out_grids,
                         dtype=dtype)
             if self.time_block > 1:
@@ -1105,6 +1249,11 @@ class PallasPlan:
                 body = _make_body_fused(self.kernel, self.info, spec,
                                         template=self.template,
                                         mem_type=self.mem_type)
+            # k>1: one VMEM frame per swap buffer (its logical window)
+            scratch = [pltpu.VMEM(tuple(self.B[ax] + 2 * self.wf[g][ax]
+                                        for ax in range(self.ndim)), dtype)
+                       for g in self.step_out_grids] \
+                if self.time_block > 1 else []
             call = pl.pallas_call(
                 body,
                 grid=self.nb,
@@ -1112,12 +1261,15 @@ class PallasPlan:
                 out_specs=self._out_specs,
                 out_shape=[jax.ShapeDtypeStruct(self.padded_shape, dtype)
                            for _ in self.step_out_grids],
+                scratch_shapes=scratch,
                 input_output_aliases=self._aliases,
                 interpret=self.backend.interpret,
                 name=(f"stencil_{self.kernel.name}_{self.template}"
                       f"_fused_step_k{self.time_block}"),
-                compiler_params=_CompilerParams(
-                    dimension_semantics=("arbitrary",) * self.ndim),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",) * self.ndim,
+                    vmem_limit_bytes=_vmem_limit(
+                        self.vmem_bytes(jnp.dtype(dtype).itemsize))),
             )
             self._calls[key] = call
         return call

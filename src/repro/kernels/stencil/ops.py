@@ -25,7 +25,7 @@ def stencil_apply(kernel: "st.Kernel",
                   template: str = "gmem",
                   block: Optional[Tuple[int, ...]] = None,
                   mem_type: Optional[str] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   region=None) -> Dict[str, jnp.ndarray]:
     """Apply a ``@st.kernel`` to raw halo-padded arrays.
 
@@ -56,7 +56,7 @@ def stencil_timeloop(kernel: "st.Kernel",
                      template: str = "gmem",
                      block: Optional[Tuple[int, ...]] = None,
                      mem_type: Optional[str] = None,
-                     interpret: bool = True,
+                     interpret: Optional[bool] = None,
                      fuse_steps: Optional[int] = None,
                      time_block: int = 1,
                      batch: int = 0) -> Dict[str, jnp.ndarray]:

@@ -8,6 +8,17 @@ initialization; smoke tests and benches keep the default single device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the stencil lowerings
+    place shards with shard_map and update sharded arrays with ``.at[]``,
+    which the ``Explicit`` axes ``jax.make_mesh`` defaults to refuse.
+    Every mesh of the repo is built here."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,13 +26,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     leading pod axis: (pod=2, data=16, model=16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Degenerate mesh for single-device smoke runs."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_scaling_mesh(n_devices: int, axis: str = "data"):
@@ -34,9 +45,7 @@ def make_scaling_mesh(n_devices: int, axis: str = "data"):
         raise ValueError(
             f"mesh wants {n_devices} devices, only {len(devs)} available "
             f"(set XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    from jax.sharding import Mesh
-    import numpy as np
-    return Mesh(np.array(devs[:n_devices]), (axis,))
+    return make_mesh((n_devices,), (axis,), devices=devs[:n_devices])
 
 
 def mesh_axes(mesh) -> dict:

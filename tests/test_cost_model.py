@@ -141,7 +141,8 @@ def test_link_rate_falls_back_without_probeable_mesh():
     assert model.rate_for("link", F32, mesh={"data": 4}) \
         == cm.DEFAULT_RATES["link"]
     import jax
-    mesh1 = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh1 = make_mesh((1,), ("data",))
     assert model.rate_for("link", F32, mesh=mesh1) \
         == cm.DEFAULT_RATES["link"]
     assert not any(k.startswith("link") for k in model._rates)
@@ -337,8 +338,9 @@ def test_tune_with_mesh_prunes_distributed_candidates():
     measures at most top_k, so distributed candidates participate in
     pruning instead of forcing exhaustive measurement."""
     import jax
+    from repro.launch.mesh import make_mesh
     k, grids = _grids()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     res = at.tune(k, grids, iters=1, space=_mesh_space(), swap=("v", "u"),
                   steps=4, fuse_space=(1,), time_block_space=(1,),
                   top_k=2, cost_model=_model(), mesh=mesh)
@@ -355,8 +357,9 @@ def test_mesh_results_skip_disk_cache(tmp_path):
     descriptor, so persisting them would leak one mesh's winner into
     every other topology."""
     import jax
+    from repro.launch.mesh import make_mesh
     k, grids = _grids()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     at.tune(k, grids, iters=1, space=[st.xla()], swap=("v", "u"),
             steps=2, fuse_space=(1,), top_k=None, cost_model=_model(),
             cache_dir=str(tmp_path), mesh=mesh)

@@ -26,6 +26,7 @@ def _run_in_subprocess(code: str):
 
 CHECK_BODY = """
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import Mesh
 from repro.core import dsl as st, suite
 from repro.kernels.stencil import ref
@@ -36,7 +37,7 @@ def check(name, mesh_shape, axis_names, grid_axes, overlap, inner):
     k = suite.get_kernel(name)
     nd = k.info.ndim
     interior = (32, 32) if nd == 2 else (16, 16, 32)
-    mesh = jax.make_mesh(mesh_shape, axis_names)
+    mesh = make_mesh(mesh_shape, axis_names)
     u = st.grid(dtype=st.f32, shape=interior, order=k.info.order).randomize(0)
     v = st.grid(dtype=st.f32, shape=interior, order=k.info.order)
     be = st.distributed(grid_axes=grid_axes, overlap=overlap, inner=inner)
@@ -89,7 +90,7 @@ def test_distributed_rejects_bad_divisibility():
 from repro.core import distributed as dist
 from jax.sharding import Mesh
 k = suite.get_kernel('star2d1r')
-mesh = jax.make_mesh((8,), ('data',))
+mesh = make_mesh((8,), ('data',))
 try:
     dist.lower_distributed(k.ir, {'u': (1, 1), 'v': (1, 1)}, (30, 30), None,
                            st.distributed(grid_axes=('data', None)), mesh)
@@ -109,9 +110,10 @@ def test_timeloop_fused_distributed_matches_per_step():
     is clamped to the window and to k·h ≤ local extent by HaloSpec."""
     _run_in_subprocess("""
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core import acoustic, dsl as st
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = (48, 32, 24)
 
 def mk():
@@ -144,10 +146,11 @@ def test_time_skewed_matches_stepwise():
     the zero grid-halo is re-imposed between fused steps."""
     _run_in_subprocess("""
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import acoustic, dsl as st
 from repro.core import distributed as dist
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = (48, 32, 24)  # local (12,16): fits k*h <= 12 for k=3, h=4
 k_ir = acoustic.acoustic_iso_kernel.ir
 halos = {g: acoustic.acoustic_iso_kernel.info.halo for g in k_ir.grid_params}
@@ -186,10 +189,11 @@ def test_time_skew_composes_with_inner_time_block():
     maps onto (kw / k_inner) skewing groups."""
     _run_in_subprocess("""
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import acoustic, dsl as st
 from repro.core import distributed as dist
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = (48, 32, 24)  # local (12,16): k_total*h <= 12 for k_total=3, h=4
 k_ir = acoustic.acoustic_iso_kernel.ir
 halos = {g: acoustic.acoustic_iso_kernel.info.halo for g in k_ir.grid_params}
@@ -251,11 +255,12 @@ def test_fused_window_single_program_and_collective_model():
     model the distributed cost model and the regression guard rely on."""
     _run_in_subprocess("""
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import acoustic, dsl as st
 from repro.core import distributed as dist
 from repro.launch import hlo_analysis
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = (48, 32, 24)
 k_ir = acoustic.acoustic_iso_kernel.ir
 
@@ -311,10 +316,11 @@ def test_distributed_batched_multi_device():
     per-scenario distributed runs."""
     _run_in_subprocess("""
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.core import dsl as st, suite
 
 B, STEPS, FUSE = 3, 6, 3
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 k = suite.get_kernel("star2d2r")
 shape = (32, 24)
 rng = np.random.default_rng(0)
@@ -354,11 +360,12 @@ def test_resilient_distributed_multi_device(tmp_path):
     real multi-device mesh."""
     _run_in_subprocess(f"""
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core import dsl as st, suite
 from repro.core.timeloop import TimeloopEngine, run_resilient
 from repro.train.fault_tolerance import FailureInjector
 
-mesh = jax.make_mesh((4,), ("data",))
+mesh = make_mesh((4,), ("data",))
 k = suite.get_kernel("star2d1r")
 shape = (24, 16)
 halos = {{g: (k.info.order,) * k.info.ndim for g in k.ir.grid_params}}

@@ -54,13 +54,14 @@ def _run_in_subprocess(code: str):
 # comparable across backends — and only interiors are physics)
 PRELUDE = """
 import jax
+from repro.launch.mesh import make_mesh
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from repro.core import adjoint, dsl as st, suite
 from repro.core import timeloop as tl
 
 assert len(jax.devices()) == 4, jax.devices()
-MESH = jax.make_mesh((4,), ("data",))
+MESH = make_mesh((4,), ("data",))
 K = suite.get_kernel("star2d2r")
 SHAPE = (16, 12)
 O = K.info.order
@@ -172,12 +173,13 @@ def test_sharded_coefficient_and_scalar_grads_batched():
     per-scenario gradients stay isolated."""
     _run_in_subprocess("""
 import jax
+from repro.launch.mesh import make_mesh
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from repro.core import adjoint, dsl as st
 from repro.core import timeloop as tl
 
-MESH = jax.make_mesh((4,), ("data",))
+MESH = make_mesh((4,), ("data",))
 
 @st.kernel
 def heat(u: st.grid, v: st.grid, c: st.grid, a: st.f32):

@@ -33,13 +33,14 @@ def test_lower_compile_all_kinds_small_mesh():
     import dataclasses, jax, jax.numpy as jnp
     from jax.sharding import NamedSharding
     from repro import configs, sharding
+    from repro.launch.mesh import make_mesh
     from repro.configs.shapes import ShapeSpec, input_specs
     from repro.models import api
     from repro.serving.serve_loop import make_serve_step
     from repro.train import train_loop
     from repro.train.optimizer import OptConfig
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = configs.tiny(configs.get("granite-8b"))
     for kind, seq, gb in (("train", 64, 8), ("prefill", 64, 8),
                           ("decode", 64, 8)):
@@ -87,6 +88,7 @@ def test_sharded_train_matches_single_device():
     """One sharded train step on a (2,2) mesh == single-device step."""
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro import configs, sharding
     from repro.configs.shapes import ShapeSpec
     from repro.train import data, train_loop
@@ -102,7 +104,7 @@ def test_sharded_train_matches_single_device():
     state0 = train_loop.init_state(cfg, jax.random.PRNGKey(0))
     ref_state, ref_m = jax.jit(step)(jax.tree.map(jnp.copy, state0), batch)
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     st_shard = train_loop.state_shardings(cfg, mesh)
     b_shard = sharding.batch_shardings(
         cfg, mesh, jax.tree.map(lambda x: x, batch))

@@ -3,6 +3,7 @@ kv-cache sharding mode selection, grad-accumulator pinning, the HLO
 charge model, the stencil traffic model, and time-skew input validation.
 """
 import jax
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -42,7 +43,7 @@ def test_kv_cache_mode_selection():
     cfg8 = configs.get("granite-8b")     # kv=8
     cfg16 = configs.get("gemma-7b")      # kv=16
     assert sharding.kv_cache_mode(cfg8) is None   # no mesh
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with sharding.use_activation_mesh(mesh):
         assert sharding.kv_cache_mode(cfg8) is None  # model axis size 1
 
@@ -119,7 +120,7 @@ def test_time_skew_validation_errors():
     from repro.core import acoustic, distributed as dist, dsl as st
     k = acoustic.acoustic_iso_kernel
     halos = {g: k.info.halo for g in k.ir.grid_params}
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="swap"):
         dist.lower_distributed(
             k.ir, halos, (32, 32, 32), None,

@@ -9,6 +9,7 @@ existing checkpoint directory.  The multi-device distributed variant
 lives in tests/test_distributed.py's subprocess harness."""
 import numpy as np
 import jax
+from repro.launch.mesh import make_mesh
 import pytest
 
 from repro.core import dsl as st, suite
@@ -97,7 +98,7 @@ def test_failures_beyond_budget_raise(tmp_path):
 def test_resilient_distributed_single_device(tmp_path):
     """The fused sharded window restores bit-exactly too (single-device
     mesh here; the 4-device run is exercised in test_distributed.py)."""
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     be = st.distributed(grid_axes=("data", None), time_steps=2)
     inits = _inits(3)
     ref = _engine(be, mesh).run(dict(inits), {}, STEPS, 4)

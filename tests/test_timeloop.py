@@ -261,6 +261,22 @@ def test_time_block4_matches_per_step_suite(name):
                                    err_msg=f"{name}/time_block=4/{g}")
 
 
+@pytest.mark.parametrize("name,block", [("star3d2r", (8, 8, 64)),
+                                        ("star3d2r", (16, 12, 128)),
+                                        ("star2d2r", (12, 64))])
+@pytest.mark.parametrize("time_block", (1, 2))
+def test_unaligned_user_block_matches_per_step(name, block, time_block):
+    """A user's block that is not a multiple of the (8, 128) tile keeps
+    exact windows on the unaligned axes (no tile rounding there), so the
+    fused path still matches the per-step reference."""
+    want = _per_step_reference(name)
+    got = _fused(name, st.pallas(template="gmem", block=block,
+                                 time_block=time_block), fuse=STEPS)
+    for g in ("u", "v"):
+        np.testing.assert_allclose(got[g], want[g], atol=1e-6,
+                                   err_msg=f"{name}/{block}/k={time_block}")
+
+
 def test_time_block_acoustic_matches_per_step():
     """Multi-grid kernel (coefficient fields + scalar) through the temporal
     path."""
@@ -302,6 +318,11 @@ def test_time_block_reduces_counted_traffic():
     assert p1.hbm_bytes_per_step() > p4.hbm_bytes_per_step()
 
 
+def _extent(spec):
+    """Block extent of a BlockSpec whose dims may be ``pl.Element``."""
+    return tuple(getattr(d, "block_size", d) for d in spec.block_shape)
+
+
 def test_time_block_outputs_never_alias_read_windows():
     """The k>1 kernel reads k·h-deep windows that overlap *neighboring*
     blocks' output interiors; on real TPU the grid runs sequentially, so
@@ -321,11 +342,14 @@ def test_time_block_outputs_never_alias_read_windows():
     # block it writes, nothing another instance's window reads
     for i in plan._aliases:
         assert tuple(plan._in_specs[i].block_shape) == tuple(plan.B)
-    # every read window keeps its expanded halo and is never aliased
+    # every read window keeps its expanded (tile-aligned) halo and is
+    # never aliased
     for gi, g in enumerate(plan.opnd_grids):
         assert gi not in plan._aliases
-        assert tuple(plan._in_specs[gi].block_shape) == tuple(
-            plan.B[ax] + 2 * plan.wf[g][ax] for ax in range(plan.ndim))
+        assert _extent(plan._in_specs[gi]) == tuple(
+            plan.B[ax] + 2 * plan.wa[g][ax] for ax in range(plan.ndim))
+        assert all(plan.wa[g][ax] >= plan.wf[g][ax]
+                   for ax in range(plan.ndim))
     # the double-buffered stage refuses to run without destinations
     with pytest.raises(ValueError, match="double-buffer"):
         plan.step({g: jnp.zeros(plan.padded_shape, jnp.float32)
@@ -335,7 +359,7 @@ def test_time_block_outputs_never_alias_read_windows():
     p1 = codegen.plan_pallas(k.ir, halos, (16, 24),
                              st.pallas(template="gmem"), swap=("v", "u"))
     for gi in p1._aliases:
-        assert tuple(p1._in_specs[gi].block_shape) == tuple(p1.B)
+        assert _extent(p1._in_specs[gi]) == tuple(p1.B)
 
 
 def test_defaulted_fuse_keeps_between_cadence():
@@ -404,6 +428,36 @@ def test_autotune_expansion_keeps_user_time_block():
                                       time_block_space=(1, 2))
     tbs = [getattr(bb, "time_block", 1) for bb, _ in cands]
     assert tbs == [8, 1, 2], cands
+
+
+def test_time_block_plan_names_the_body_it_runs():
+    """At ``time_block>1`` the plan carries the template and memory type
+    its kernel really runs (3-D blocked templates stream plane by plane
+    through 'vmem' shift; every 2-D template runs one whole-block body), and
+    the tuner measures each such program once."""
+    k3 = suite.get_kernel("star3d2r")
+    halos = {g: k3.info.halo for g in k3.ir.grid_params}
+    for t, want in (("gmem", ("shift", "vmem")), ("f4", ("shift", "vmem")),
+                    ("semi", ("semi", "vmem"))):
+        plan = codegen.plan_pallas(
+            k3.ir, halos, (8, 10, 16),
+            st.pallas(template=t, mem_type="vmem" if t == "semi" else None,
+                      time_block=2), swap=("v", "u"))
+        assert (plan.template, plan.mem_type) == want, t
+    k2 = suite.get_kernel("star2d2r")
+    halos2 = {g: k2.info.halo for g in k2.ir.grid_params}
+    plan = codegen.plan_pallas(k2.ir, halos2, (16, 24),
+                               st.pallas(template="smem", time_block=2),
+                               swap=("v", "u"))
+    assert plan.template == "gmem"
+    space = [st.pallas(template=t) for t in ("gmem", "smem", "f4")]
+    space.append(st.pallas(template="shift", mem_type="vmem"))
+    cands = autotune._normalize_space(space, 3, (8, 10, 16), ("v", "u"),
+                                      steps=4, fuse_space=(4,),
+                                      time_block_space=(1, 2))
+    deep = [b for b, _ in cands if b.time_block == 2]
+    assert [(b.template, b.mem_type) for b in deep] == [("shift", "vmem")]
+    assert len(cands) == 5, cands
 
 
 def test_distributed_window_decomposition_keeps_inner_depth():

@@ -297,10 +297,11 @@ def test_distributed_batched_matches_serial(time_steps, fuse):
     equal B independent runs.  Single-device mesh — the multi-device
     variant lives in test_distributed.py's subprocess harness."""
     import jax
+    from repro.launch.mesh import make_mesh
     k = suite.get_kernel("star2d1r")
     shape = (12, 18)
     inits = _inits(k, shape)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     be = st.distributed(grid_axes=("data", None), time_steps=time_steps)
 
     ser = _serial(k, shape, inits, st.xla(), fuse=fuse)

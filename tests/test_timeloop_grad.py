@@ -6,10 +6,10 @@ donation-under-AD regression (``_donate_ok``)."""
 import math
 
 import jax
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import adjoint, dsl as st, suite
 from repro.core import timeloop as tl
@@ -61,7 +61,7 @@ def _check_grad_vs_fd(fn, arrays, scal, tag, n_probes=2, eps=1e-6,
 # ---- gradient == finite differences: xla ----------------------------------
 @pytest.mark.parametrize("fuse", (1, 4, None))
 def test_grad_vs_fd_xla(fuse):
-    with enable_x64():
+    with jax.enable_x64():
         eng, arrays = _engine(st.xla())
         fn = adjoint.differentiable_run(eng, 5, fuse_steps=fuse)
         _check_grad_vs_fd(fn, arrays, {}, f"xla/fuse={fuse}")
@@ -71,7 +71,7 @@ def test_grad_vs_fd_xla(fuse):
 @pytest.mark.parametrize("template", TEMPLATES)
 @pytest.mark.parametrize("time_block", (1, 4))
 def test_grad_vs_fd_pallas_templates(template, time_block):
-    with enable_x64():
+    with jax.enable_x64():
         backend = st.pallas(template=template, interpret=True,
                             time_block=time_block)
         eng, arrays = _engine(backend)
@@ -85,7 +85,7 @@ def test_grad_vs_fd_pallas_templates(template, time_block):
 
 # ---- scalar + coefficient-grid gradients (the FWI surface) ----------------
 def test_grad_flows_to_scalars_and_coefficient_grid():
-    with enable_x64():
+    with jax.enable_x64():
         @st.kernel
         def heat(u: st.grid, v: st.grid, c: st.grid, a: st.f32):
             v.at(0, 0).set(u.at(0, 0) + a * c.at(0, 0) * (
@@ -121,7 +121,7 @@ def test_grad_flows_to_scalars_and_coefficient_grid():
 
 # ---- batched scenarios differentiate per-scenario -------------------------
 def test_batched_grads_are_per_scenario():
-    with enable_x64():
+    with jax.enable_x64():
         B = 3
         eng, arrays = _engine(st.xla(), shape=(8, 10), batch=B)
         fn = adjoint.differentiable_run(eng, 4)
@@ -139,7 +139,7 @@ def test_batched_grads_are_per_scenario():
 
 # ---- masked windows: adjoint freezes masked cells and exhausted steps -----
 def test_masked_window_grads_freeze_masked_cells():
-    with enable_x64():
+    with jax.enable_x64():
         B = 2
         shape = (8, 10)
         eng, arrays = _engine(st.xla(), shape=shape, batch=B)
@@ -211,7 +211,7 @@ def test_primal_matches_engine_run(backend):
 
 
 def test_between_hook_is_differentiated():
-    with enable_x64():
+    with jax.enable_x64():
         eng, arrays = _engine(st.xla(), shape=(6, 8))
 
         def between(t, arrs):
@@ -268,7 +268,7 @@ def test_dsl_distributed_backend_differentiates():
     cotangents the halo ring.  (Real multi-device coverage lives in
     tests/test_distributed_adjoint.py.)"""
     k = suite.get_kernel("star2d1r")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     grids = {g: st.grid(dtype=st.f32, shape=(8, 8), order=1).randomize(i)
              for i, g in enumerate(k.ir.grid_params)}
     fn = st.differentiable_timeloop(
@@ -320,7 +320,7 @@ def test_donate_ok_disabled_under_trace(monkeypatch):
     seen = {}
 
     def probe(x):
-        seen["donate"] = tl._donate_ok()
+        seen["donate"] = tl._donate_ok(arrays={"u": x})
         return x
 
     jax.make_jaxpr(probe)(jnp.zeros(1))
@@ -340,6 +340,6 @@ def test_differentiable_engine_windows_do_not_donate(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     monkeypatch.setattr(jax, "jit", spy_jit)
-    eng, _ = _engine(st.xla(), dtype=jnp.float32, shape=(6, 8))
-    eng._window(2)
+    eng, arrays = _engine(st.xla(), dtype=jnp.float32, shape=(6, 8))
+    eng.run(arrays, {}, 2)
     assert captured["donate"] == ()

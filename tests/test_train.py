@@ -186,10 +186,11 @@ def test_elastic_reshard_restore(tmp_path):
     checkpoint.save(d, 1, state)
     code = textwrap.dedent(f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro import configs, sharding
         from repro.train import checkpoint, train_loop
         cfg = configs.tiny(configs.get("granite-8b"))
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         specs = train_loop.state_specs(cfg)
         shard = train_loop.state_shardings(cfg, mesh)
         st = checkpoint.restore({d!r}, specs, shardings=shard)

@@ -48,6 +48,13 @@ default schedule (fuse ≈ ⌈√T⌉, stride thinning the checkpoints back to
 ≈ ⌈√T⌉ when the caller forces a smaller hook cadence) every term is
 O(√T).
 
+The compiled program names its three kinds of work with
+``jax.named_scope`` (``core/trace.py``): ``adjoint_forward`` for the
+forward sweep, ``adjoint_replay`` for the backward pass's checkpoint
+replays and ``adjoint_vjp`` for the per-window pullbacks; each device
+operation's ``op_name`` carries its scope.  ``CHECKPOINT_STATS`` counts
+the most recently traced pass.
+
 ``between`` hooks are supported when they are PURE traceable functions
 ``between(t, arrays) -> arrays`` (e.g. jnp source injection); they fire
 at the same window boundaries as ``TimeloopEngine.run`` and are
@@ -66,17 +73,19 @@ import jax
 import jax.numpy as jnp
 
 from . import lowering
+from . import trace as _trace
 
 __all__ = ["ceil_sqrt", "window_schedule", "checkpoint_stride",
            "differentiable_run", "resilient_grad", "CHECKPOINT_STATS",
            "reset_stats"]
 
-#: trace-time accounting of the most recent forward/backward pass —
-#: ``checkpoints`` is the number of carries saved as VJP residuals (the
-#: O(√T) bound tests pin), ``replayed_windows``/``vjp_windows`` count the
-#: backward pass's recompute work
+#: trace-time accounting of the most recently traced pass — the forward
+#: sets ``forward_windows`` and ``checkpoints`` (the carries saved as VJP
+#: residuals, the O(√T) bound tests pin); the backward zeroes, then counts,
+#: ``replayed_windows`` and ``vjp_windows``, its recompute work
 CHECKPOINT_STATS: Dict[str, int] = {
-    "checkpoints": 0, "replayed_windows": 0, "vjp_windows": 0}
+    "forward_windows": 0, "checkpoints": 0, "replayed_windows": 0,
+    "vjp_windows": 0}
 
 
 def reset_stats() -> None:
@@ -242,12 +251,24 @@ class _AdjointPlan:
             scal[n] = a
         return scal
 
+    def forward_window(self, i: int, carry, scalars):
+        """Window i of the forward sweep on the engine's programs."""
+        with jax.named_scope(_trace.ADJOINT_FORWARD):
+            return self.chain(i, self.primal_window)(carry, scalars)
+
+    def replay_window(self, i: int, carry, scalars):
+        """Window i recomputed from a checkpoint in the backward pass:
+        the forward's program, bit-exact with it."""
+        with jax.named_scope(_trace.ADJOINT_REPLAY):
+            return self.chain(i, self.primal_window)(carry, scalars)
+
     def vjp_window(self, i: int, carry, scalars, cot):
         """Pull ``cot`` backward through window i linearized at ``carry``;
         returns (carry cotangent, scalar cotangent contribution)."""
-        _, vjp_fn = jax.vjp(self.chain(i, self.adjoint_window),
-                            carry, scalars)
-        d_carry, d_scal = vjp_fn(dict(cot))
+        with jax.named_scope(_trace.ADJOINT_VJP):
+            _, vjp_fn = jax.vjp(self.chain(i, self.adjoint_window),
+                                carry, scalars)
+            d_carry, d_scal = vjp_fn(dict(cot))
         return dict(d_carry), d_scal
 
 
@@ -314,7 +335,7 @@ def differentiable_run(engine,
     def core(arrays, scalars):
         carry = dict(arrays)
         for i in range(W):
-            carry = plan.chain(i, plan.primal_window)(carry, scalars)
+            carry = plan.forward_window(i, carry, scalars)
         return carry
 
     def core_fwd(arrays, scalars):
@@ -323,7 +344,8 @@ def differentiable_run(engine,
         for i in range(W):
             if i % stride == 0:
                 ckpts.append(carry)
-            carry = plan.chain(i, plan.primal_window)(carry, scalars)
+            carry = plan.forward_window(i, carry, scalars)
+        CHECKPOINT_STATS["forward_windows"] = W
         CHECKPOINT_STATS["checkpoints"] = len(ckpts)
         return carry, (tuple(ckpts), scalars)
 
@@ -331,6 +353,8 @@ def differentiable_run(engine,
         ckpts, scalars = res
         g_scal = _zeros_like_tree(scalars)
         cot = dict(cot)
+        CHECKPOINT_STATS["replayed_windows"] = 0
+        CHECKPOINT_STATS["vjp_windows"] = 0
         for seg in reversed(range(n_ckpts)):
             first = seg * stride
             last = min(first + stride, W)
@@ -338,8 +362,7 @@ def differentiable_run(engine,
             # engine's own programs — bit-exact with the forward pass
             carries = [ckpts[seg]]
             for i in range(first, last - 1):
-                carries.append(
-                    plan.chain(i, plan.primal_window)(carries[-1], scalars))
+                carries.append(plan.replay_window(i, carries[-1], scalars))
                 CHECKPOINT_STATS["replayed_windows"] += 1
             # pull the cotangent backward one window at a time through the
             # reference adjoint, linearized at the replayed carry
@@ -426,8 +449,7 @@ def resilient_grad(engine,
                 ckpts = list(state["ckpts"])
                 ckpts[wi // stride] = dict(state["carry"])
                 state["ckpts"] = tuple(ckpts)
-            state["carry"] = plan.chain(wi, plan.primal_window)(
-                state["carry"], scal)
+            state["carry"] = plan.forward_window(wi, state["carry"], scal)
         elif wi == W:                                # seed the cotangent
             value, cot = jax.value_and_grad(loss)(state["carry"])
             state["value"] = jnp.asarray(value, state["value"].dtype)
@@ -438,8 +460,7 @@ def resilient_grad(engine,
             last = min(first + stride, W)
             carries = [dict(state["ckpts"][seg])]
             for i in range(first, last - 1):
-                carries.append(plan.chain(i, plan.primal_window)(
-                    carries[-1], scal))
+                carries.append(plan.replay_window(i, carries[-1], scal))
             cot, g_scal = state["cot"], state["g_scal"]
             for i in reversed(range(first, last)):
                 cot, gs = plan.vjp_window(i, carries[i - first], scal, cot)

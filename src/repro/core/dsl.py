@@ -43,6 +43,7 @@ from . import analysis as _analysis
 from . import frontend as _frontend
 from . import ir as _ir
 from . import lowering as _lowering
+from . import trace as _trace
 
 __all__ = [
     "grid", "kernel", "target", "map", "timeloop", "launch",
@@ -385,14 +386,10 @@ class _Ctx(threading.local):
     def __init__(self):
         self.backend: Backend = xla()
         self.mesh = None
-        self.profile: Dict[str, float] = {}
         self.active = False
         self.fuse_steps: Optional[int] = None
         self.time_block: Optional[int] = None
         self.autotune: Optional[Dict[str, object]] = None
-
-    def add(self, phase: str, dt: float):
-        self.profile[phase] = self.profile.get(phase, 0.0) + dt
 
 
 _CTX = _Ctx()
@@ -403,9 +400,21 @@ class LaunchResult:
     """What a launched target returns.
 
     ``value`` is the target function's own return value; ``profile`` maps
-    phase names to accumulated seconds — ``codegen`` (trace + lower),
-    ``comp`` (XLA compile), ``kernel`` (device execution, blocked until
-    ready) and ``total`` (wall clock for the whole launch).
+    phase names to the summed wall seconds of the host spans
+    (``core/trace.py``) that feed them; a phase that never ran is absent:
+
+    - ``codegen``: ``dsl.codegen``, kernel and engine builds (trace and
+      lower; an ``st.map`` build includes its compile);
+    - ``comp``: ``dsl.compile``, the ahead-of-time XLA compile of an
+      ``st.map`` program, and ``engine.compile``, a fusion-window cache
+      miss (the program is built there; XLA compiles it at its first
+      dispatch, inside ``kernel``);
+    - ``kernel``: ``dsl.map``, one ``st.map`` application blocked until
+      ready, and ``engine.window``, one fusion window from the layout
+      stage through dispatch to ``engine.sync``;
+    - ``layout``: ``layout.to_padded``, the engine's pad into its padded
+      layout (inside ``kernel``);
+    - ``total``: ``dsl.launch``, the whole launch.
     """
     value: object
     profile: Dict[str, float]
@@ -493,16 +502,14 @@ def _apply_kernel(k: Kernel, args, begin, end):
                                              for n, g in grids.items())), region)
     entry = k._cache.get(key)
     if entry is None:
-        t0 = time.perf_counter()
-        entry = _build_callable(k, backend, grids, region)
-        _CTX.add("codegen", time.perf_counter() - t0)
+        with _trace.span(_trace.CODEGEN, "codegen"):
+            entry = _build_callable(k, backend, grids, region)
         k._cache[key] = entry
 
     arrays = {n: g.data for n, g in grids.items()}
-    t0 = time.perf_counter()
-    out = entry(arrays, scalars)
-    jax.block_until_ready(out)
-    _CTX.add("kernel", time.perf_counter() - t0)
+    with _trace.span(_trace.MAP, "kernel"):
+        out = entry(arrays, scalars)
+        jax.block_until_ready(out)
     for name in k.ir.output_grids():
         grids[name].data = out[name]
     return None
@@ -649,12 +656,11 @@ def _run_timeloop(k: Kernel, args, call: _TimeloopCall) -> TimeloopResult:
            swap, id(mesh) if mesh is not None else None, batch)
     engine = k._cache.get(key)
     if engine is None:
-        t0 = time.perf_counter()
         halos = {n: g.halo for n, g in grids.items()}
-        engine = _tl.TimeloopEngine(
-            k.ir, halos, interior, backend, swap=swap, mesh=mesh,
-            profile_cb=_CTX.add if _CTX.active else None, batch=batch)
-        _CTX.add("codegen", time.perf_counter() - t0)
+        with _trace.span(_trace.CODEGEN, "codegen"):
+            engine = _tl.TimeloopEngine(
+                k.ir, halos, interior, backend, swap=swap, mesh=mesh,
+                batch=batch)
         k._cache[key] = engine
     # clamp the window to the loop length; report the size that actually
     # runs.  Temporal depth (time_block / time_steps) never alters the
@@ -812,15 +818,14 @@ def _build_callable(k: Kernel, backend: Backend, grids: Dict[str, grid], region)
     else:
         raise ValueError(backend.kind)
 
-    # explicit AOT compile so the profiler separates comp from kernel time;
+    # explicit AOT compile so the profile separates comp from kernel time;
     # scalars are always passed as f32, so the signature is fixed
     abstract_arrays = {n: jax.ShapeDtypeStruct(g.data.shape, g.data.dtype)
                        for n, g in grids.items()}
     abstract_scalars = {n: jax.ShapeDtypeStruct((), jnp.float32)
                         for n, _ in k.ir.scalar_params}
-    t0 = time.perf_counter()
-    compiled = jitted.lower(abstract_arrays, abstract_scalars).compile()
-    _CTX.add("comp", time.perf_counter() - t0)
+    with _trace.span(_trace.MAP_COMPILE, "comp"):
+        compiled = jitted.lower(abstract_arrays, abstract_scalars).compile()
 
     def run(arrays, scalars):
         scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
@@ -832,37 +837,36 @@ def _build_callable(k: Kernel, backend: Backend, grids: Dict[str, grid], region)
 # launch
 # --------------------------------------------------------------------------
 class _Launcher:
-    def __init__(self, backend: Backend, mesh=None, profile: bool = True,
+    def __init__(self, backend: Backend, mesh=None,
                  fuse_steps: Optional[int] = None,
                  time_block: Optional[int] = None,
                  autotune: Optional[Dict[str, object]] = None):
-        self.backend, self.mesh, self.profile = backend, mesh, profile
+        self.backend, self.mesh = backend, mesh
         self.fuse_steps = fuse_steps
         self.time_block = time_block
         self.autotune = autotune
 
     def __call__(self, tgt: Callable):
         def run(*args, **kw) -> LaunchResult:
-            prev = (_CTX.backend, _CTX.mesh, _CTX.profile, _CTX.active,
+            prev = (_CTX.backend, _CTX.mesh, _CTX.active,
                     _CTX.fuse_steps, _CTX.time_block, _CTX.autotune)
             _CTX.backend, _CTX.mesh = self.backend, self.mesh
-            _CTX.profile, _CTX.active = {}, True
+            _CTX.active = True
             _CTX.fuse_steps = self.fuse_steps
             _CTX.time_block = self.time_block
             _CTX.autotune = self.autotune
-            t0 = time.perf_counter()
             try:
-                value = tgt(*args, **kw)
+                with _trace.collect() as prof, \
+                        _trace.span(_trace.LAUNCH, "total"):
+                    value = tgt(*args, **kw)
             finally:
-                prof = _CTX.profile
-                prof["total"] = time.perf_counter() - t0
-                (_CTX.backend, _CTX.mesh, _CTX.profile, _CTX.active,
+                (_CTX.backend, _CTX.mesh, _CTX.active,
                  _CTX.fuse_steps, _CTX.time_block, _CTX.autotune) = prev
             return LaunchResult(value=value, profile=prof)
         return run
 
 
-def launch(backend: Backend = None, mesh=None, profile: bool = True,
+def launch(backend: Backend = None, mesh=None,
            fuse_steps: Optional[int] = None,
            time_block: Optional[int] = None,
            autotune: bool = False,
@@ -898,6 +902,6 @@ def launch(backend: Backend = None, mesh=None, profile: bool = True,
                   "fuse_space": tuple(autotune_fuse_space),
                   "time_block_space": tuple(autotune_time_block_space),
                   "cost_model": autotune_cost_model}
-    return _Launcher(backend or xla(), mesh=mesh, profile=profile,
+    return _Launcher(backend or xla(), mesh=mesh,
                      fuse_steps=fuse_steps, time_block=time_block,
                      autotune=at_cfg)

@@ -66,7 +66,6 @@ API is ``st.timeloop(...)`` / ``st.launch(..., fuse_steps=K)`` in
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, Mapping, Optional, Tuple
 
 import jax
@@ -74,6 +73,7 @@ import jax.numpy as jnp
 
 from . import ir as _ir
 from . import lowering
+from . import trace as _trace
 
 
 def window_parts(kw: int, k_inner: int) -> list:
@@ -190,7 +190,6 @@ class TimeloopEngine:
                  backend,
                  swap: Optional[Tuple[str, str]] = None,
                  mesh=None,
-                 profile_cb: Optional[Callable[[str, float], None]] = None,
                  batch: int = 0,
                  differentiable: bool = False):
         self.kernel = kernel
@@ -203,7 +202,6 @@ class TimeloopEngine:
         self.batch = int(batch)
         if self.batch < 0:
             raise ValueError("batch must be >= 0 (0 = unbatched)")
-        self._profile_cb = profile_cb
         self._windows: Dict[Tuple[int, bool], Callable] = {}
         self._plan = self._plan1 = None
         self.time_block = 1
@@ -236,10 +234,6 @@ class TimeloopEngine:
         self.max_fuse: Optional[int] = None
 
     # -- helpers -----------------------------------------------------------
-    def _add(self, phase: str, dt: float) -> None:
-        if self._profile_cb is not None:
-            self._profile_cb(phase, dt)
-
     def _window(self, kw: int, masked: bool = False,
                 donate: bool = False) -> Callable:
         """Compiled fused program for a window of ``kw`` steps.
@@ -249,9 +243,13 @@ class TimeloopEngine:
         ``_donate_ok``) lets the program reuse its first argument's
         buffers."""
         fn = self._windows.get((kw, masked, donate))
-        if fn is not None:
-            return fn
-        t0 = time.perf_counter()
+        if fn is None:
+            with _trace.span(_trace.COMPILE, "comp"):
+                fn = self._build_window(kw, masked, donate)
+            self._windows[(kw, masked, donate)] = fn
+        return fn
+
+    def _build_window(self, kw: int, masked: bool, donate: bool) -> Callable:
         donate = (0,) if donate else ()
         if masked:
             if self.backend.kind not in ("xla", "distributed") \
@@ -328,8 +326,6 @@ class TimeloopEngine:
                 self.kernel, self.interior, self.backend, self.mesh,
                 self.swap, kw, batch=self.batch,
                 differentiable=self.differentiable)
-        self._add("comp", time.perf_counter() - t0)
-        self._windows[(kw, masked, bool(donate))] = fn
         return fn
 
     def window_for(self, steps: int, fuse_steps: Optional[int] = None) -> int:
@@ -352,8 +348,8 @@ class TimeloopEngine:
         these callables to snapshot checkpointed carries and the backward
         pass replays them bit-exactly from those checkpoints (the same
         replay primitive ``run_resilient`` relies on).  Unlike
-        ``_run_window``, no wall-clock profiling, host syncs, or modeled-
-        traffic counters fire here — the function must be traceable inside
+        ``_run_window``, no window spans, host syncs, or modeled-traffic
+        counters fire here — the function must be traceable inside
         another transform."""
         if masked or self.backend.kind in ("xla", "distributed"):
             return self._window(kw, masked=masked)
@@ -448,37 +444,45 @@ class TimeloopEngine:
         t = 0
         while t < steps:
             kw = min(fuse, steps - t)
-            t0 = time.perf_counter()
-            if masked:
-                arrays = self._window(
-                    kw, masked=True,
-                    donate=_donate_ok(self.differentiable, arrays))(
-                    arrays, scal, mask, jnp.int32(t), limits)
-            else:
-                arrays = self._run_window(arrays, scal, kw)
-            jax.block_until_ready(arrays)
-            self._add("kernel", time.perf_counter() - t0)
+            with _trace.span(_trace.WINDOW, "kernel"):
+                if masked:
+                    fn = self._window(
+                        kw, masked=True,
+                        donate=_donate_ok(self.differentiable, arrays))
+                    with _trace.span(_trace.DISPATCH):
+                        arrays = fn(arrays, scal, mask, jnp.int32(t), limits)
+                else:
+                    arrays = self._run_window(arrays, scal, kw)
+                with _trace.span(_trace.SYNC):
+                    jax.block_until_ready(arrays)
             t += kw
             if between is not None and t < steps:
-                arrays = between(t, arrays) or arrays
+                with _trace.span(_trace.HOOK):
+                    arrays = between(t, arrays) or arrays
         return arrays
 
     def _run_window(self, arrays, scal, kw):
         donate = _donate_ok(self.differentiable, arrays)
-        if self.backend.kind == "xla":
-            return self._window(kw, donate=donate)(arrays, scal)
-        if self.backend.kind == "pallas":
-            plan = self._plan
-            t0 = time.perf_counter()
+        if self.backend.kind != "pallas":
+            # xla, and distributed: one program advances the whole window
+            # (exchange groups + remainder + every leapfrog rotation
+            # happen in-program)
+            fn = self._window(kw, donate=donate)
+            with _trace.span(_trace.DISPATCH):
+                return fn(arrays, scal)
+        plan = self._plan
+        with _trace.span(_trace.TO_PADDED, "layout"):
             if self.batch:
                 # vmapped layout stage: still ONE pad per grid per window
                 # (eager vmap pads all B scenarios in a single batched op)
                 padded = jax.vmap(plan.to_padded)(arrays)
             else:
                 padded = plan.to_padded(arrays)     # ONE pad/grid/window
-            self._add("layout", time.perf_counter() - t0)
             plan.count_window(kw, batch=max(1, self.batch))  # modeled HBM
-            padded = self._window(kw, donate=donate)(padded, scal)
+        fn = self._window(kw, donate=donate)
+        with _trace.span(_trace.DISPATCH):
+            padded = fn(padded, scal)
+        with _trace.span(_trace.FROM_PADDED):
             # the device program rotated padded buffers kw times; apply the
             # same parity to the full host arrays so halos travel with
             # their buffers, then write the padded interiors back
@@ -487,9 +491,6 @@ class TimeloopEngine:
             if self.batch:
                 return jax.vmap(plan.from_padded)(padded, arrays)
             return plan.from_padded(padded, arrays)
-        # distributed: one program advances the whole window (exchange
-        # groups + remainder + every leapfrog rotation happen in-program)
-        return self._window(kw, donate=donate)(arrays, scal)
 
 
 def run_timeloop(kernel: _ir.StencilIR,

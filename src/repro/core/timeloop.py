@@ -26,6 +26,9 @@ that fusion for all three backends:
                  the temporal depth.  The k-step invocations double-buffer
                  the swap pair: outputs land in spare padded buffers that
                  ping-pong with the read buffers between invocations.
+                 The loop runs steps (and invocations) in pairs, so no
+                 buffer changes carry slot inside it; the host applies the
+                 window's leapfrog parity by renaming.
                  Modeled HBM traffic per window is accumulated in
                  ``codegen.TRAFFIC_COUNT`` alongside ``PAD_COUNT``.
   distributed  — the ENTIRE fusion window runs as ONE jitted shard_map'd
@@ -157,6 +160,24 @@ def _rotate(arrays: Dict[str, jnp.ndarray], swap) -> Dict[str, jnp.ndarray]:
     return out
 
 
+#: host-side accounting of the Pallas windows ``TimeloopEngine.run`` has
+#: sent: ``paired_steps`` ran two to a loop iteration, ``single_steps`` ran
+#: outside the loop (a window's odd step or odd k-step invocation)
+WINDOW_STATS: Dict[str, int] = {
+    "windows": 0, "paired_steps": 0, "single_steps": 0}
+
+
+def reset_window_stats() -> None:
+    """Zero ``WINDOW_STATS``.
+
+    >>> reset_window_stats()
+    >>> WINDOW_STATS["windows"]
+    0
+    """
+    for k in WINDOW_STATS:
+        WINDOW_STATS[k] = 0
+
+
 def _donate_ok(differentiable: bool = False, arrays=None) -> bool:
     """Whether a fused-window program may donate the input buffers
     ``arrays`` (any pytree).
@@ -249,6 +270,13 @@ class TimeloopEngine:
             self._windows[(kw, masked, donate)] = fn
         return fn
 
+    def _window_split(self, kw: int) -> Tuple[int, int]:
+        """A Pallas window of ``kw`` steps as (k-step invocations, single
+        steps): ⌊kw/k⌋ and the remainder, or ``kw`` single steps when the
+        plan is not temporally blocked."""
+        k = self.time_block
+        return divmod(kw, k) if k > 1 else (0, kw)
+
     def _build_window(self, kw: int, masked: bool, donate: bool) -> Callable:
         donate = (0,) if donate else ()
         if masked:
@@ -278,12 +306,12 @@ class TimeloopEngine:
         elif self.backend.kind == "pallas":
             plan, plan1, swap = self._plan, self._plan1, self.swap
             k = self.time_block
-            m, r = divmod(kw, k)
+            m, r = self._window_split(kw)
 
             def win(padded, scalars):
                 from jax import lax
 
-                def body_k(_, carry):
+                def invoke(carry, rename=True):
                     # double-buffered k-step invocation: outputs land in
                     # the spare buffers (the kernel must not write the
                     # buffers whose k·h windows other blocks still read),
@@ -296,23 +324,39 @@ class TimeloopEngine:
                     p, sp = carry
                     out = plan.step(p, scalars, spares=sp)
                     new_sp = {g: p[g] for g in plan.step_out_grids}
-                    if swap and k % 2:
+                    if rename and k % 2:
                         out = _rotate(out, swap)
                         new_sp = _rotate(new_sp, swap)
                     return out, new_sp
 
-                def body_1(_, p):
+                def single(p, rename=True):
                     out = plan1.step(p, scalars)
-                    return _rotate(out, swap) if swap else out
+                    return _rotate(out, swap) if swap and rename else out
+
+                def run(n, one, carry):
+                    # n applications of ``one``, two per loop iteration: a
+                    # pair nets the leapfrog renaming to the identity, so
+                    # every buffer leaves an iteration in the carry slot it
+                    # came in with and XLA aliases the kernel's writes in
+                    # place (a rename inside the loop body costs a copy of
+                    # each renamed buffer per iteration).  An odd last one
+                    # runs after the loop without renaming; the caller
+                    # applies the window's parity to the names.
+                    if n >= 2:
+                        carry = lax.fori_loop(
+                            0, n // 2, lambda _, c: one(one(c)), carry)
+                    return one(carry, rename=False) if n % 2 else carry
 
                 p = dict(padded)
-                if m and k > 1:
-                    p, _ = lax.fori_loop(0, m, body_k,
-                                         (p, plan.make_spares(p)))
-                elif m:
-                    p = lax.fori_loop(0, m, body_1, p)
+                if m:
+                    p, _ = run(m, invoke, (p, plan.make_spares(p)))
+                # an odd k-step invocation left its k renames unapplied:
+                # the remainder steps run on the names as the leapfrog
+                # sees them, and hand back the slots they were given
+                flip = m % 2 and k % 2
                 if r:
-                    p = lax.fori_loop(0, r, body_1, p)
+                    p = run(r, single, _rotate(p, swap) if flip else p)
+                    p = _rotate(p, swap) if flip else p
                 return p
             if self.batch:
                 # XLA's batching rule lifts the scenario axis into an extra
@@ -362,6 +406,7 @@ class TimeloopEngine:
             padded = win(padded, scal)
             if swap and kw % 2:
                 arrays = _rotate(arrays, swap)
+                padded = _rotate(padded, swap)
             return (jax.vmap(plan.from_padded)(padded, arrays) if batch
                     else plan.from_padded(padded, arrays))
         return fn
@@ -479,15 +524,22 @@ class TimeloopEngine:
             else:
                 padded = plan.to_padded(arrays)     # ONE pad/grid/window
             plan.count_window(kw, batch=max(1, self.batch))  # modeled HBM
+            m, r = self._window_split(kw)
+            WINDOW_STATS["windows"] += 1
+            WINDOW_STATS["paired_steps"] += (m - m % 2) * self.time_block \
+                + r - r % 2
+            WINDOW_STATS["single_steps"] += m % 2 * self.time_block + r % 2
         fn = self._window(kw, donate=donate)
         with _trace.span(_trace.DISPATCH):
             padded = fn(padded, scal)
         with _trace.span(_trace.FROM_PADDED):
-            # the device program rotated padded buffers kw times; apply the
-            # same parity to the full host arrays so halos travel with
-            # their buffers, then write the padded interiors back
+            # the device program keeps every buffer under the name it
+            # came in with; apply the window's leapfrog parity to the
+            # padded AND the full arrays so halos travel with their
+            # buffers, then write the padded interiors back
             if self.swap and kw % 2:
                 arrays = _rotate(arrays, self.swap)
+                padded = _rotate(padded, self.swap)
             if self.batch:
                 return jax.vmap(plan.from_padded)(padded, arrays)
             return plan.from_padded(padded, arrays)

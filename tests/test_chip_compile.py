@@ -11,6 +11,7 @@ The topology is described inside a module-scope fixture, never at import:
 only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,91 @@ def test_acoustic_256_window_compiles(one_chip):
     hlo = _compile_window(acoustic.acoustic_iso_kernel, (256, 256, 256),
                           backend, ("p0", "p1"), 10, one_chip)
     assert "tpu_custom_call" in hlo
+
+
+def _computations(hlo):
+    """Compiled HLO text as {computation name: its lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _loop_computations(comps):
+    """Every computation that runs inside a while loop: the loop bodies
+    and conditions and whatever they call, transitively."""
+    def called(lines, keys):
+        out = set()
+        for line in lines:
+            for ref in re.findall(r"\b(%s)=(\{[^}]*\}|%%[\w.\-]+)"
+                                  % "|".join(keys), line):
+                out |= set(re.findall(r"%([\w.\-]+)", ref[1]))
+        return out
+    todo = called((ln for c in comps.values() for ln in c),
+                  ("body", "condition"))
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo |= called(comps[c], ("calls", "to_apply", "body", "condition",
+                                  "branch_computations"))
+    return seen
+
+
+def _padded_copies(lines, padded_shape):
+    """Lines that copy a whole padded buffer."""
+    shape = re.escape("f32[%s]" % ",".join(map(str, padded_shape)))
+    return [ln for ln in lines
+            if re.search(shape + r"\S*\s+copy(-start)?\(", ln)]
+
+
+@pytest.mark.parametrize("name,kw,time_block", [
+    ("star3d4r", 1, 1), ("star3d4r", 2, 1), ("star3d4r", 3, 1),
+    ("star3d4r", 100, 1), ("acoustic", 1, 1), ("acoustic", 8, 1),
+    ("star3d4r", 9, 4), ("star3d4r", 17, 4),
+])
+def test_window_loop_keeps_buffers_in_place(one_chip, name, kw, time_block):
+    """The program ``_run_window`` sends at 512³ keeps every padded buffer
+    in its loop slot: the loop runs steps (or k-step invocations) in
+    pairs that net the leapfrog rename to the identity, so no iteration
+    copies a 528×528×768 buffer, and an odd last step runs in place with
+    the host renaming.  The only whole-buffer copies left in the program
+    are the ``time_block>1`` spares, once per window."""
+    kernel, swap = ((acoustic.acoustic_iso_kernel, ("p0", "p1"))
+                    if name == "acoustic"
+                    else (suite.get_kernel(name), ("v", "u")))
+    halos = {g: kernel.info.halo for g in kernel.ir.grid_params}
+    eng = TimeloopEngine(kernel.ir, halos, (512, 512, 512),
+                         st.pallas(template="gmem", time_block=time_block,
+                                   interpret=False), swap=swap)
+    plan = eng._plan
+    padded = {g: jax.ShapeDtypeStruct(plan.padded_shape, jnp.float32,
+                                      sharding=one_chip)
+              for g in plan.opnd_grids}
+    scalars = {n: jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+               for n, _ in kernel.ir.scalar_params}
+    hlo = eng._window(kw, donate=True).lower(padded, scalars) \
+        .compile().as_text()
+    comps = _computations(hlo)
+    loops = _loop_computations(comps)
+    if kw >= 4 * time_block:
+        assert loops, "the window has no loop left to check"
+    in_loop = [ln for c in loops for ln in _padded_copies(comps[c],
+                                                          plan.padded_shape)]
+    assert not in_loop, [ln[:100] for ln in in_loop]
+    spares = len(plan.step_out_grids) if time_block > 1 else 0
+    copies = [ln for c in comps.values()
+              for ln in _padded_copies(c, plan.padded_shape)]
+    assert len(copies) == spares, [ln[:100] for ln in copies]
 
 
 @pytest.mark.parametrize("block", [(8, 8, 64), (16, 12, 128)])

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.core import autotune, dsl as st, suite
+from repro.core import timeloop as tl
 from repro.kernels.stencil import codegen, ops
 
 STEPS = 5
@@ -65,6 +66,56 @@ def test_fused_matches_per_step_pallas(name, template):
     for g in ("u", "v"):
         np.testing.assert_allclose(got[g], want[g], atol=1e-6,
                                    err_msg=f"{name}/{template}/{g}")
+
+
+# ---- the Pallas window keeps its buffers in their loop slots -------------
+# 9 steps in windows of kw: whole windows of both parities, and a last
+# window shorter than the rest
+@pytest.mark.parametrize("kw", (1, 2, 3, 7, 8))
+def test_fused_pallas_window_bit_exact(kw):
+    """Steps run in pairs inside the window's loop and an odd last step
+    runs in place, with the host applying the leapfrog parity: the same
+    kernel on the same operands in the same order, so the fused window
+    equals the per-step reference bit for bit."""
+    want = _per_step_reference("star2d2r", steps=9)
+    got = _fused("star2d2r", st.pallas(template="gmem"), fuse=kw, steps=9)
+    for g in ("u", "v"):
+        np.testing.assert_array_equal(got[g], want[g],
+                                      err_msg=f"kw={kw}/{g}")
+
+
+@pytest.mark.parametrize("kw", (1, 2, 3, 7, 8))
+def test_window_stats_count_paired_and_single_steps(kw):
+    """One window of kw steps runs kw // 2 pairs in its loop and the odd
+    step after it."""
+    tl.reset_window_stats()
+    _fused("star2d1r", st.pallas(template="gmem"), fuse=kw, steps=kw)
+    assert tl.WINDOW_STATS == {"windows": 1, "paired_steps": kw // 2 * 2,
+                               "single_steps": kw % 2}
+    tl.reset_window_stats()
+
+
+def test_batched_pallas_window_matches_serial():
+    """The vmapped window keeps the pairing and the host-side parity: an
+    odd window over two scenarios equals each scenario run alone."""
+    k = suite.get_kernel("star2d2r")
+    halos = {g: k.info.halo for g in k.ir.grid_params}
+    interior = (16, 24)
+    rng = np.random.default_rng(1)
+    arrays = {g: jnp.asarray(rng.standard_normal(
+        (2,) + tuple(s + 2 * h for s, h in zip(interior, halos[g]))),
+        jnp.float32) for g in k.ir.grid_params}
+    backend = st.pallas(template="gmem")
+    got = tl.TimeloopEngine(k.ir, halos, interior, backend, swap=("v", "u"),
+                            batch=2).run(arrays, {}, 7, 3)
+    serial = tl.TimeloopEngine(k.ir, halos, interior, backend,
+                               swap=("v", "u"))
+    for b in range(2):
+        want = serial.run({g: a[b] for g, a in arrays.items()}, {}, 7, 3)
+        for g in ("u", "v"):
+            np.testing.assert_array_equal(np.asarray(got[g][b]),
+                                          np.asarray(want[g]),
+                                          err_msg=f"scenario {b}/{g}")
 
 
 @pytest.mark.parametrize("template", ("smem", "f4", "unroll", "semi"))
@@ -219,7 +270,7 @@ def _per_step_reference_shape(name, shape, steps=STEPS):
 
 @pytest.mark.parametrize("template", ("gmem", "smem", "f4", "shift",
                                       "unroll", "semi"))
-@pytest.mark.parametrize("time_block", (1, 2, 4))
+@pytest.mark.parametrize("time_block", (1, 2, 3, 4))
 def test_time_block_matches_per_step_all_templates(template, time_block):
     """k steps per kernel invocation == k per-step applications, on a shape
     not divisible by the block, for every template; the outermost k·h cells
@@ -400,6 +451,26 @@ def test_time_block_odd_rotation_parity(time_block):
     for g in ("u", "v"):
         np.testing.assert_allclose(got[g], want[g], atol=1e-6,
                                    err_msg=f"k={time_block}/{g}")
+
+
+# (k, steps): an odd count of k-step invocations (the pair in the loop,
+# the third after it) and a remainder of one or two single steps, under
+# an even and an odd depth
+@pytest.mark.parametrize("time_block,steps", ((2, 7), (3, 10), (3, 11)))
+def test_time_block_odd_invocation_count(time_block, steps):
+    """An odd k-step invocation after the loop leaves its renames to the
+    host, so the single steps after it must run on the leapfrog's names."""
+    name = "star2d1r"
+    want = _per_step_reference_shape(name, TB_SHAPE, steps)
+    k = suite.get_kernel(name)
+    grids = _mk_grids_shape(name, TB_SHAPE)
+    st.launch(backend=st.pallas(template="gmem", time_block=time_block))(
+        lambda u, v: st.timeloop(steps, swap=("v", "u"))(k)(u, v))(
+        grids["u"], grids["v"])
+    for g in ("u", "v"):
+        np.testing.assert_allclose(np.asarray(grids[g].data), want[g],
+                                   atol=1e-6,
+                                   err_msg=f"k={time_block}/{steps}/{g}")
 
 
 def test_explicit_whole_loop_fuse_not_rounded():

@@ -26,6 +26,14 @@ Design
   dispatch and the latency-hiding scheduler overlaps each group's
   ppermutes with the deep-interior pre-pass across steps, not just
   within one.  All exchange geometry comes from ``core.halo.HaloSpec``.
+* Every program here takes and returns the grids in their halo-padded
+  form, sharded on the mesh: the crop to interiors, the shard_map'd
+  program and the write-back of the outputs' interiors are ONE jitted
+  program (``_on_padded``) whose results stay ``NamedSharding``s of the
+  mesh.  Where each split axis's padded extent is a multiple of its mesh
+  axis (the interior's is, so where the mesh axis divides ``2h``), no
+  array of the global extent is ever whole on one device; otherwise that
+  axis is left unsplit (``_on_padded``).
 
 Halo traffic per step per shard is ``h · (local surface)`` — the classic
 reason stencils scale to thousands of nodes: the collective term shrinks
@@ -33,18 +41,17 @@ relative to compute as local volume grows.
 """
 from __future__ import annotations
 
-from functools import partial
 from typing import Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import analysis, ir, lowering
 from . import halo as _halo
 from . import timeloop as _tl
+from . import trace as _trace
 
 
 def _halo_exchange(local: jnp.ndarray, axis: int, mesh_axis: str,
@@ -69,6 +76,92 @@ def _halo_exchange(local: jnp.ndarray, axis: int, mesh_axis: str,
     right_halo = lax.ppermute(edge(0, h), mesh_axis,
                               [(i + 1, i) for i in range(k - 1)])
     return left_halo, right_halo
+
+
+#: host-side accounting of the distributed windows ``TimeloopEngine.run``
+#: has sent: exchange ``groups`` and the per-shard ``collective_bytes`` the
+#: window's ``HaloSpec`` prices (``window_collective_bytes``)
+EXCHANGE_STATS: Dict[str, int] = {
+    "windows": 0, "groups": 0, "collective_bytes": 0}
+
+
+def reset_exchange_stats() -> None:
+    """Zero ``EXCHANGE_STATS``.
+
+    >>> reset_exchange_stats()
+    >>> EXCHANGE_STATS["collective_bytes"]
+    0
+    """
+    for k in EXCHANGE_STATS:
+        EXCHANGE_STATS[k] = 0
+
+
+def count_window(fn, itemsize: int) -> None:
+    """Add one sent window of ``lower_distributed_window``'s ``fn`` to
+    ``EXCHANGE_STATS``."""
+    EXCHANGE_STATS["windows"] += 1
+    EXCHANGE_STATS["groups"] += sum(n for n, _ in fn.groups)
+    EXCHANGE_STATS["collective_bytes"] += fn.spec.window_collective_bytes(
+        fn.window, itemsize, max(1, fn.batch))
+
+
+def _scal_f32(v):
+    return jnp.asarray(v, jnp.float32)
+
+
+def _on_padded(core, grids, outs, interior_shape, mesh, gspec, *,
+               donate: bool = False, scal_in=_scal_f32):
+    """``fn(arrays, scalars, *extra) -> arrays`` on the halo-padded grids,
+    as ONE jitted program: every grid of ``grids`` cropped to its interior
+    (under the ``layout.crop`` scope), ``core(interiors, scalars, *extra)``
+    (the shard_map'd program), and the interiors of ``outs`` written back
+    (under ``layout.write_back``).  Every grid leaves the program sharded
+    by ``gspec`` on ``mesh``, except along an axis whose padded extent the
+    mesh axis does not divide (an odd halo ``h`` on a (4,) mesh: 2h + n
+    rows), which no ``NamedSharding`` can split: that axis comes back
+    whole on every device, as it must go in.  XLA re-aligns the padded
+    shards (1032 rows over 4 chips: 258 each) with the interior's (256
+    each) by collective permutes, so where every split axis divides, no
+    array of the global extent is whole on one device.  The grids come in as
+    ``NamedSharding``s of the mesh or not yet committed to a device; the
+    grid halo is assumed zero.  ``donate`` lets the program reuse the
+    input grids' buffers."""
+    off = len(gspec) - len(interior_shape)
+
+    def idx(a):
+        o = [(n - i) // 2 for n, i in zip(a.shape[off:], interior_shape)]
+        return ((slice(None),) * off
+                + tuple(slice(b, b + n) for b, n in zip(o, interior_shape)))
+
+    def write_back(full, inner):
+        # a pad and an elementwise select, which the partitioner keeps in
+        # shards; an indexed update (a scatter) it would gather whole
+        pads = [(0, 0)] * off + [((n - i) // 2, (n - i) // 2) for n, i in
+                                 zip(full.shape[off:], interior_shape)]
+        inside = True
+        for ax, (lo, _) in enumerate(pads[off:]):
+            c = lax.broadcasted_iota(jnp.int32, full.shape, ax + off)
+            inside = inside & (c >= lo) & (c < lo + interior_shape[ax])
+        return jnp.where(inside, jnp.pad(inner.astype(full.dtype), pads),
+                         full)
+
+    def sharded(a):
+        spec = P(*(m if m is None or a.shape[ax] % mesh.shape[m] == 0
+                   else None for ax, m in enumerate(gspec)))
+        return lax.with_sharding_constraint(a, NamedSharding(mesh, spec))
+
+    def program(arrays, scalars, *extra):
+        with jax.named_scope(_trace.MeshScope.LAYOUT_CROP):
+            interiors = {g: arrays[g][idx(arrays[g])] for g in grids}
+        out = core(interiors, {n: scal_in(v) for n, v in scalars.items()},
+                   *extra)
+        result = dict(arrays)
+        with jax.named_scope(_trace.MeshScope.LAYOUT_WRITE_BACK):
+            for g in outs:
+                result[g] = write_back(arrays[g], out[g])
+        return {g: sharded(a) for g, a in result.items()}
+
+    return jax.jit(program, donate_argnums=(0,) if donate else ())
 
 
 def lower_distributed(kernel: ir.StencilIR,
@@ -209,25 +302,8 @@ def lower_distributed(kernel: ir.StencilIR,
         check_vma=False)
 
     jitted = jax.jit(shmapped)
-
-    def fn(arrays: Dict[str, jnp.ndarray], scalars: Dict[str, jnp.ndarray]):
-        """arrays are *full* (grid-halo'd) host arrays; the grid halo is
-        assumed zero in the distributed path."""
-        interiors = {}
-        for g in all_grids:
-            o = (np.asarray(arrays[g].shape) - np.asarray(interior_shape)) // 2
-            idx = tuple(slice(int(o[ax]), int(o[ax]) + interior_shape[ax])
-                        for ax in range(ndim))
-            interiors[g] = arrays[g][idx]
-        scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
-        out = jitted(interiors, scal)
-        result = dict(arrays)
-        for g in out_grids:
-            o = (np.asarray(arrays[g].shape) - np.asarray(interior_shape)) // 2
-            idx = tuple(slice(int(o[ax]), int(o[ax]) + interior_shape[ax])
-                        for ax in range(ndim))
-            result[g] = arrays[g].at[idx].set(out[g])
-        return result
+    fn = _on_padded(jitted, all_grids, out_grids, interior_shape, mesh,
+                    specs)
 
     fn.jitted = jitted
     fn.shmapped = shmapped
@@ -345,25 +421,7 @@ def _lower_time_skewed(kernel, info, interior_shape, backend, mesh,
         out_specs={swap[0]: specs, swap[1]: specs},
         check_vma=False)
     jitted = jax.jit(shmapped)
-
-    def fn(arrays, scalars):
-        interiors = {}
-        for g in all_grids:
-            o = (np.asarray(arrays[g].shape)
-                 - np.asarray(interior_shape)) // 2
-            idx = tuple(slice(int(o[ax]), int(o[ax]) + interior_shape[ax])
-                        for ax in range(ndim))
-            interiors[g] = arrays[g][idx]
-        scal = {n: jnp.asarray(v, jnp.float32) for n, v in scalars.items()}
-        out = jitted(interiors, scal)
-        result = dict(arrays)
-        for g in out:
-            o = (np.asarray(arrays[g].shape)
-                 - np.asarray(interior_shape)) // 2
-            idx = tuple(slice(int(o[ax]), int(o[ax]) + interior_shape[ax])
-                        for ax in range(ndim))
-            result[g] = arrays[g].at[idx].set(out[g])
-        return result
+    fn = _on_padded(jitted, all_grids, swap, interior_shape, mesh, specs)
 
     fn.jitted = jitted
     fn.shmapped = shmapped
@@ -385,9 +443,17 @@ def lower_distributed_window(kernel: ir.StencilIR,
                              window: int,
                              batch: int = 0,
                              differentiable: bool = False,
-                             masked: bool = False):
+                             masked: bool = False,
+                             donate: bool = False):
     """Build ``fn(arrays, scalars) -> arrays`` advancing ``window``
     leapfrog steps in ONE jitted shard_map'd program.
+
+    ``fn`` takes and returns the halo-padded grids, sharded on the mesh:
+    the crop to interiors, the window and the write-back are one program
+    (``_on_padded``), so a window costs one dispatch and, where the mesh
+    divides the padded extents, nothing of the global extent is gathered
+    on one device.  ``donate=True`` lets it
+    reuse the input grids' buffers.
 
     The window decomposes into depth-``k`` exchange groups
     (``k = time_steps × inner time_block``; ``HaloSpec.group_depths``):
@@ -491,6 +557,7 @@ def lower_distributed_window(kernel: ir.StencilIR,
     def maybe_vmap(f):
         return jax.vmap(f, in_axes=(0, 0)) if batch else f
 
+    @jax.named_scope(_trace.MeshScope.HALO_EXCHANGE)
     def pad_exchanged(arr, widths):
         """Axis-by-axis halo pad: real ppermute slabs on decomposed axes,
         zeros elsewhere (the global zero grid-halo)."""
@@ -577,17 +644,19 @@ def lower_distributed_window(kernel: ir.StencilIR,
             if i == 0 and pre_fn is not None:
                 # deep interior from local-only data — no dependency on the
                 # ppermutes above, so the scheduler overlaps them with this
-                pre_in = dict(zcoeffs)
-                pre_in[older] = pad_zero(carry[older], gh[older])
-                pre_in[newer] = pad_zero(carry[newer], gh[newer])
-                pre_out = pre_fn(pre_in, scalars)[older]
-                deep = sub.deep_interior()
-                out_f = padded[older].at[reg_idx(ew, deep)].set(
-                    pre_out[reg_idx(gh[older], deep)])
-                for band_fn, breg in band_fns:
-                    bres = band_fn(padded, scalars)[older]
-                    out_f = out_f.at[reg_idx(ew, breg)].set(
-                        bres[reg_idx(ew, breg)])
+                with jax.named_scope(_trace.MeshScope.HALO_INTERIOR):
+                    pre_in = dict(zcoeffs)
+                    pre_in[older] = pad_zero(carry[older], gh[older])
+                    pre_in[newer] = pad_zero(carry[newer], gh[newer])
+                    pre_out = pre_fn(pre_in, scalars)[older]
+                    deep = sub.deep_interior()
+                    out_f = padded[older].at[reg_idx(ew, deep)].set(
+                        pre_out[reg_idx(gh[older], deep)])
+                with jax.named_scope(_trace.MeshScope.HALO_BANDS):
+                    for band_fn, breg in band_fns:
+                        bres = band_fn(padded, scalars)[older]
+                        out_f = out_f.at[reg_idx(ew, breg)].set(
+                            bres[reg_idx(ew, breg)])
             else:
                 out_f = step_fn(padded, scalars)[older]
             new_field = zero_outside_global(out_f, ew)
@@ -714,49 +783,25 @@ def lower_distributed_window(kernel: ir.StencilIR,
                 check_vma=False)
         bwd_jitted = jax.jit(bwd_shmapped)
 
-    if differentiable and not masked:
+    if differentiable:
+        # residuals are the window INPUTS (one carry), not per-step
+        # intermediates — the backward program re-linearizes from them;
+        # a masked window's mask, start and limits get no cotangent
         @jax.custom_vjp
-        def core(interiors, scal):
-            return jitted(interiors, scal)
+        def core(interiors, scal, *extra):
+            return jitted(interiors, scal, *extra)
 
-        def _core_fwd(interiors, scal):
-            # residuals are the window INPUTS (one carry), not per-step
-            # intermediates — the backward program re-linearizes from them
-            return jitted(interiors, scal), (interiors, scal)
+        def _core_fwd(interiors, scal, *extra):
+            return jitted(interiors, scal, *extra), (interiors, scal, extra)
 
         def _core_bwd(res, cot):
-            interiors, scal = res
-            return bwd_jitted(interiors, dict(cot), scal)
+            interiors, scal, extra = res
+            d_in, d_scal = bwd_jitted(interiors, dict(cot), scal, *extra)
+            return (d_in, d_scal) + (None,) * len(extra)
 
         core.defvjp(_core_fwd, _core_bwd)
     else:
         core = jitted
-
-    def _masked_core(mask, start, limits):
-        """custom_vjp over (interiors, scalars) with the non-differentiable
-        mask/start/limits operands closed over (they are concrete per
-        call; the compiled programs underneath are shared)."""
-        @jax.custom_vjp
-        def core_m(interiors, scal):
-            return jitted(interiors, scal, mask, start, limits)
-
-        def fwd(interiors, scal):
-            return (jitted(interiors, scal, mask, start, limits),
-                    (interiors, scal))
-
-        def bwd(res, cot):
-            interiors, scal = res
-            return bwd_jitted(interiors, dict(cot), scal, mask, start,
-                              limits)
-
-        core_m.defvjp(fwd, bwd)
-        return core_m
-
-    def _interior_idx(arr):
-        o = (np.asarray(arr.shape[off:]) - np.asarray(interior_shape)) // 2
-        return ((slice(None),) * off
-                + tuple(slice(int(o[ax]), int(o[ax]) + interior_shape[ax])
-                        for ax in range(ndim)))
 
     def _scal_in(v):
         # floating dtypes pass through (the f64 adjoint path must not be
@@ -766,40 +811,13 @@ def lower_distributed_window(kernel: ir.StencilIR,
             else a.astype(jnp.float32)
 
     if masked:
-        def fn(arrays: Dict[str, jnp.ndarray],
-               scalars: Dict[str, jnp.ndarray],
-               mask, start, limits):
-            """arrays are *full* (grid-halo'd) host arrays with a leading
-            batch axis; the grid halo is assumed zero."""
-            interiors = {g: arrays[g][_interior_idx(arrays[g])]
-                         for g in all_grids}
-            scal = {n: _scal_in(v) for n, v in scalars.items()}
-            mask = jnp.asarray(mask, bool)
-            start = jnp.asarray(start, jnp.int32)
-            limits = jnp.asarray(limits, jnp.int32)
-            if differentiable:
-                out = _masked_core(mask, start, limits)(interiors, scal)
-            else:
-                out = jitted(interiors, scal, mask, start, limits)
-            result = dict(arrays)
-            for g in (older, newer):
-                full = jnp.asarray(arrays[g])
-                result[g] = full.at[_interior_idx(full)].set(out[g])
-            return result
-    else:
-        def fn(arrays: Dict[str, jnp.ndarray],
-               scalars: Dict[str, jnp.ndarray]):
-            """arrays are *full* (grid-halo'd) host arrays, optionally with
-            a leading batch axis; the grid halo is assumed zero."""
-            interiors = {g: arrays[g][_interior_idx(arrays[g])]
-                         for g in all_grids}
-            scal = {n: _scal_in(v) for n, v in scalars.items()}
-            out = core(interiors, scal)
-            result = dict(arrays)
-            for g in (older, newer):
-                full = jnp.asarray(arrays[g])
-                result[g] = full.at[_interior_idx(full)].set(out[g])
-            return result
+        def masked_core(interiors, scal, mask, start, limits):
+            return core(interiors, scal, jnp.asarray(mask, bool),
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(limits, jnp.int32))
+    fn = _on_padded(masked_core if masked else core, all_grids, swap,
+                    interior_shape, mesh, gspec, donate=donate,
+                    scal_in=_scal_in)
 
     fn.jitted = jitted
     fn.shmapped = shmapped
@@ -812,6 +830,7 @@ def lower_distributed_window(kernel: ir.StencilIR,
     fn.depth = depth
     fn.window = window
     fn.groups = groups
+    fn.batch = batch
     fn.masked = masked
     fn.differentiable = differentiable
     return fn

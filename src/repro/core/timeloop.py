@@ -32,7 +32,11 @@ that fusion for all three backends:
                  Modeled HBM traffic per window is accumulated in
                  ``codegen.TRAFFIC_COUNT`` alongside ``PAD_COUNT``.
   distributed  — the ENTIRE fusion window runs as ONE jitted shard_map'd
-                 program (``distributed.lower_distributed_window``): a
+                 program (``distributed.lower_distributed_window``) on
+                 the halo-padded grids, sharded on the mesh in and out
+                 (the crop to interiors and the write-back are part of
+                 it, so nothing of the global extent is gathered where
+                 the mesh divides the padded extents): a
                  ``lax.fori_loop`` over depth-k exchange groups
                  (k = ``time_steps`` × inner ``time_block``) plus an
                  unrolled remainder group, each group = one k·h-wide halo
@@ -43,7 +47,8 @@ that fusion for all three backends:
                  / between-hook cadence; ``time_steps``/``time_block`` set
                  only the exchange *depth* within the window.  Batched
                  scenarios ride a leading unsharded axis inside the same
-                 program.
+                 program.  Each window sent adds its exchange groups and
+                 modeled collective bytes to ``distributed.EXCHANGE_STATS``.
 
 The host syncs only at fusion-window boundaries; an optional ``between``
 hook runs there (e.g. acoustic source injection).
@@ -290,7 +295,8 @@ class TimeloopEngine:
                 fn = _dist.lower_distributed_window(
                     self.kernel, self.interior, self.backend, self.mesh,
                     self.swap, kw, batch=self.batch,
-                    differentiable=self.differentiable, masked=True)
+                    differentiable=self.differentiable, masked=True,
+                    donate=bool(donate))
             else:
                 win = lowering.lower_jax_window_masked(
                     self.kernel, self.halos, self.interior, self.swap, kw)
@@ -369,7 +375,7 @@ class TimeloopEngine:
             fn = _dist.lower_distributed_window(
                 self.kernel, self.interior, self.backend, self.mesh,
                 self.swap, kw, batch=self.batch,
-                differentiable=self.differentiable)
+                differentiable=self.differentiable, donate=bool(donate))
         return fn
 
     def window_for(self, steps: int, fuse_steps: Optional[int] = None) -> int:
@@ -511,8 +517,16 @@ class TimeloopEngine:
         if self.backend.kind != "pallas":
             # xla, and distributed: one program advances the whole window
             # (exchange groups + remainder + every leapfrog rotation
-            # happen in-program)
+            # happen in-program; on the distributed path the crop of the
+            # sharded grids to their interiors and the write-back too)
             fn = self._window(kw, donate=donate)
+            if self.backend.kind == "distributed":
+                from . import distributed as _dist
+                # the geometry of the program as built and cached, also
+                # where a wrapper of ``_window`` hands back its own callable
+                _dist.count_window(self._windows[(kw, False, donate)],
+                                   jnp.dtype(arrays[self.swap[0]].dtype)
+                                   .itemsize)
             with _trace.span(_trace.DISPATCH):
                 return fn(arrays, scal)
         plan = self._plan

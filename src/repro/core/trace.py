@@ -8,9 +8,10 @@ span's wall time is also added to ``phase`` of the launch's
 which costs well under a microsecond while no profiler runs.
 
 The names below are stable: the benchmark's span readers and the tests
-find the engine's host path by them.  The adjoint's device work is named
-by ``jax.named_scope`` (``ADJOINT_*``), which only reaches the compiled
-program's ``op_name`` metadata.
+find the engine's host path by them.  The adjoint's device work, and the
+distributed window's layout stage and halo exchange, are named by
+``jax.named_scope`` (``ADJOINT_*``, ``MeshScope``), which only
+reaches the compiled program's ``op_name`` metadata.
 """
 from __future__ import annotations
 
@@ -49,6 +50,21 @@ COMPILE = "engine.compile"
 ADJOINT_FORWARD = "adjoint_forward"
 ADJOINT_REPLAY = "adjoint_replay"
 ADJOINT_VJP = "adjoint_vjp"
+
+
+# -- named scopes in the distributed programs (``core/distributed.py``) -----
+class MeshScope:
+    """Device-side names only, so kept apart from the host spans above."""
+    #: the crop of the halo-padded, sharded grids to their sharded interiors
+    LAYOUT_CROP = "layout.crop"
+    #: the write-back of the outputs' interiors into the halo-padded grids
+    LAYOUT_WRITE_BACK = "layout.write_back"
+    #: the ppermute halo slabs and the zero pads of one exchange
+    HALO_EXCHANGE = "halo_exchange"
+    #: the deep-interior pre-pass, which reads no exchanged cell
+    HALO_INTERIOR = "halo_interior"
+    #: the boundary bands, which wait for the exchanged slabs
+    HALO_BANDS = "halo_bands"
 
 
 class _Active(threading.local):
